@@ -249,10 +249,11 @@ def autotune(kind: str, *, H: int, Kh: int, D: int, gamma_max: int,
     from repro.kernels import quant
     from repro.kernels.fused_decode import fused_paged_decode
     from repro.kernels.fused_verify import fused_paged_verify
+    from repro.kernels.ops import interpret_mode
 
     syn = _synthetic_pool(H, Kh, D, gamma_max, block_size, seed)
     B, W, rng = syn["B"], syn["W"], syn["rng"]
-    interpret = jax.default_backend() != "tpu"
+    interpret = interpret_mode()
     k_scale = v_scale = None
     qdt = quant.storage_dtype(kv_dtype)
     if qdt is not None:

@@ -17,12 +17,19 @@ T=W+1, chunked-prefill appends at the bucketed chunk width) with per-token
 ``serving/paged.make_paged_decode_override``, minus the gather copy.
 
 Tile knobs (searched by ``kernels/autotune.py``): ``bk`` sub-tiles each
-physical block (pool viewed as ``(N * f, bk, Kh, D)``), ``depth`` fetches
+physical block (pool viewed as ``(N * f, bk, Kh * D)``), ``depth`` fetches
 that many KV tiles per grid step so their DMAs double-buffer against the
 previous tiles' attention compute.  Rows shorter than the longest row
 clamp trailing steps to their last live sub-block — the revisit elides
 the DMA and ``pl.when`` skips the compute, removing the per-step revisit
 stalls of a padded dense walk.
+
+Layout for the TPU compiler (Mosaic): every block's last two dimensions
+are whole array dimensions, so no block shape depends on the (8, 128)
+tile.  Queries go head-major ``(B, H, T, D)``; each KV tile arrives as
+``(bk, Kh * D)`` and a head's keys are the lane slice ``[kh*D, (kh+1)*D)``;
+per-token and per-slot metadata arrive as ``(T, 1)`` columns and
+``(1, bk)`` rows, so every mask is a broadcast compare.
 """
 
 from __future__ import annotations
@@ -38,80 +45,95 @@ from jax.experimental.pallas import tpu as pltpu
 NEG = -1e30
 
 
+def attend_tile(q_of, k_ref, v_ref, sc_refs, mask, m_ref, l_ref, acc_ref,
+                *, Kh: int, D: int, G: int):
+    """Fold one KV tile into every head's online-softmax state.
+
+    ``q_of(h)`` gives head h's scaled float32 queries ``(Tq, D)``;
+    ``k_ref``/``v_ref`` hold the tile as ``(1, bk, Kh * D)``; ``sc_refs``
+    is ``()`` or the ``(1, bk, Kh)`` int8/fp8 scale refs; ``mask`` is
+    ``(Tq, bk)``.  State: ``m_ref``/``l_ref`` ``(Tq, H)`` running max and
+    denominator, ``acc_ref`` ``(H, Tq, D)`` running numerator.  Shared by
+    the fused decode and verify kernels."""
+    for kh in range(Kh):
+        lanes = slice(kh * D, (kh + 1) * D)
+        k = k_ref[0, :, lanes].astype(jnp.float32)           # (bk, D)
+        v = v_ref[0, :, lanes].astype(jnp.float32)
+        if sc_refs:
+            ks_ref, vs_ref = sc_refs
+            k = k * ks_ref[0, :, kh:kh + 1]
+            v = v * vs_ref[0, :, kh:kh + 1]
+        for g in range(G):
+            h = kh * G + g
+            s = jax.lax.dot_general(q_of(h), k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG)                      # (Tq, bk)
+            m_prev = m_ref[:, h:h + 1]                       # (Tq, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # the state starts at NEG, so a fully masked prefix keeps
+            # m_safe at -1e29 and its correction factor underflows to 0
+            m_safe = jnp.maximum(m_new, -1e29)
+            p = jnp.where(mask, jnp.exp(s - m_safe), 0.0)
+            corr = jnp.exp(m_prev - m_safe)
+            l_ref[:, h:h + 1] = (l_ref[:, h:h + 1] * corr
+                                 + jnp.sum(p, axis=-1, keepdims=True))
+            m_ref[:, h:h + 1] = m_new
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+
+
+def init_state(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def finish_head(l_ref, acc_ref, h: int):
+    """Head h's normalized output ``(Tq, D)``; rows that attended nothing
+    are zero."""
+    l = l_ref[:, h:h + 1]
+    o = acc_ref[h] / jnp.maximum(l, 1e-30)
+    return jnp.where(l > 0, o, 0.0)
+
+
 def _fused_decode_kernel(bt_ref, nlive_ref, q_seg_ref, q_pos_ref, q_ref,
                          *refs, nsteps: int, depth: int, scale: float,
-                         quantized: bool = False):
+                         Kh: int, D: int, quantized: bool = False):
     group = 6 if quantized else 4
     tiles = refs[:group * depth]
     o_ref, m_ref, l_ref, acc_ref = refs[group * depth:]
+    H = q_ref.shape[1]
     b = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_state(m_ref, l_ref, acc_ref)
 
-    q_seg = q_seg_ref[0]                    # (T,)
+    q_seg = q_seg_ref[0]                    # (T, 1)
     q_pos = q_pos_ref[0]
+
+    def q_of(h):
+        return q_ref[0, h].astype(jnp.float32) * scale      # (T, D)
 
     def _tile(i, pos_ref, seg_ref, k_ref, v_ref, *sc_refs):
         t = j * depth + i
 
         @pl.when(t < nlive_ref[b])
         def _compute():
-            q = q_ref[0].astype(jnp.float32) * scale        # (T, H, D)
-            k = k_ref[0].astype(jnp.float32)                # (bk, Kh, D)
-            v = v_ref[0].astype(jnp.float32)
-            if quantized:
-                ks_ref, vs_ref = sc_refs
-                k = k * ks_ref[0][..., None]
-                v = v * vs_ref[0][..., None]
-            T, H, D = q.shape
-            bk, Kh, _ = k.shape
-            G = H // Kh
-            kv_seg = seg_ref[0]             # (bk,) -1 = invalidated slot
+            kv_seg = seg_ref[0]             # (1, bk) -1 = invalidated slot
             kv_pos = pos_ref[0]
-            qg = q.reshape(T, Kh, G, D)
-            s = jax.lax.dot_general(
-                qg.transpose(1, 2, 0, 3).reshape(Kh, G * T, D),
-                k.transpose(1, 2, 0),
-                (((2,), (1,)), ((0,), (0,))))               # (Kh, G*T, bk)
-            s = s.reshape(Kh, G, T, bk).transpose(2, 0, 1, 3)
-            mask = (q_seg[:, None] == kv_seg[None, :]) \
-                & (kv_seg[None, :] >= 0) \
-                & (kv_pos[None, :] <= q_pos[:, None])       # (T, bk)
-            s = jnp.where(mask[:, None, None, :], s, NEG)
-
-            m_prev = m_ref[...].reshape(T, Kh, G)
-            l_prev = l_ref[...].reshape(T, Kh, G)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            m_safe = jnp.maximum(m_new, -1e29)
-            p = jnp.exp(s - m_safe[..., None])
-            p = jnp.where(mask[:, None, None, :], p, 0.0)
-            corr = jnp.where(jnp.isfinite(m_prev),
-                             jnp.exp(m_prev - m_safe), 0.0)
-            l_new = l_prev * corr + jnp.sum(p, axis=-1)
-            pv = jax.lax.dot_general(
-                p.transpose(1, 2, 0, 3).reshape(Kh, G * T, bk),
-                v.transpose(1, 0, 2),
-                (((2,), (1,)), ((0,), (0,))))               # (Kh, G*T, D)
-            pv = pv.reshape(Kh, G, T, D).transpose(2, 0, 1, 3)
-            acc_ref[...] = (acc_ref[...].reshape(T, Kh, G, D)
-                            * corr[..., None] + pv).reshape(T, Kh * G, D)
-            m_ref[...] = m_new.reshape(T, Kh * G)
-            l_ref[...] = l_new.reshape(T, Kh * G)
+            mask = (q_seg == kv_seg) & (kv_seg >= 0) & (kv_pos <= q_pos)
+            attend_tile(q_of, k_ref, v_ref, sc_refs, mask, m_ref, l_ref,
+                        acc_ref, Kh=Kh, D=D, G=H // Kh)
 
     for i in range(depth):
         _tile(i, *tiles[group * i:group * (i + 1)])
 
     @pl.when(j == nsteps - 1)
     def _finish():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.maximum(l, 1e-30)[..., None]
-        o = jnp.where((l > 0)[..., None], o, 0.0)
-        o_ref[0, ...] = o.astype(o_ref.dtype)
+        for h in range(H):
+            o_ref[0, h] = finish_head(l_ref, acc_ref, h).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "depth", "interpret"))
@@ -143,10 +165,10 @@ def fused_paged_decode(q, k_pool, v_pool, pool_seg, pool_pos,
     scale = 1.0 / np.sqrt(D)
 
     quantized = k_scale is not None
-    kp = k_pool.reshape(N * f, bk, Kh, D)
-    vp = v_pool.reshape(N * f, bk, Kh, D)
-    seg_p = pool_seg.astype(jnp.int32).reshape(N * f, bk)
-    pos_p = pool_pos.astype(jnp.int32).reshape(N * f, bk)
+    kp = k_pool.reshape(N * f, bk, Kh * D)
+    vp = v_pool.reshape(N * f, bk, Kh * D)
+    seg_p = pool_seg.astype(jnp.int32).reshape(N * f, 1, bk)
+    pos_p = pool_pos.astype(jnp.int32).reshape(N * f, 1, bk)
     if quantized:
         ksp = k_scale.reshape(N * f, bk, Kh)
         vsp = v_scale.reshape(N * f, bk, Kh)
@@ -166,50 +188,51 @@ def fused_paged_decode(q, k_pool, v_pool, pool_seg, pool_pos,
     def clamp(b, j, i, nl):
         return jnp.minimum(j * depth + i, jnp.maximum(nl[b], 1) - 1)
 
-    def kv_map(i):
+    def tile_map(i):
         return lambda b, j, bt_s, nl: \
-            (bt_s[b, clamp(b, j, i, nl)], 0, 0, 0)
-
-    def slot_map(i):
-        return lambda b, j, bt_s, nl: (bt_s[b, clamp(b, j, i, nl)], 0)
-
-    def sc_map(i):
-        return lambda b, j, bt_s, nl: (bt_s[b, clamp(b, j, i, nl)], 0, 0)
+            (bt_s[b, clamp(b, j, i, nl)], 0, 0)
 
     tile_specs = []
     tile_args = []
     for i in range(depth):
-        tile_specs += [pl.BlockSpec((1, bk), slot_map(i)),
-                       pl.BlockSpec((1, bk), slot_map(i)),
-                       pl.BlockSpec((1, bk, Kh, D), kv_map(i)),
-                       pl.BlockSpec((1, bk, Kh, D), kv_map(i))]
+        tile_specs += [pl.BlockSpec((1, 1, bk), tile_map(i)),
+                       pl.BlockSpec((1, 1, bk), tile_map(i)),
+                       pl.BlockSpec((1, bk, Kh * D), tile_map(i)),
+                       pl.BlockSpec((1, bk, Kh * D), tile_map(i))]
         tile_args += [pos_p, seg_p, kp, vp]
         if quantized:
-            tile_specs += [pl.BlockSpec((1, bk, Kh), sc_map(i)),
-                           pl.BlockSpec((1, bk, Kh), sc_map(i))]
+            tile_specs += [pl.BlockSpec((1, bk, Kh), tile_map(i)),
+                           pl.BlockSpec((1, bk, Kh), tile_map(i))]
             tile_args += [ksp, vsp]
+
+    def row_map(b, j, bt_s, nl):
+        return (b, 0, 0)
+
+    def head_map(b, j, bt_s, nl):
+        return (b, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, nsteps),
         in_specs=[
-            pl.BlockSpec((1, T), lambda b, j, bt_s, nl: (b, 0)),
-            pl.BlockSpec((1, T), lambda b, j, bt_s, nl: (b, 0)),
-            pl.BlockSpec((1, T, H, D), lambda b, j, bt_s, nl: (b, 0, 0, 0)),
+            pl.BlockSpec((1, T, 1), row_map),
+            pl.BlockSpec((1, T, 1), row_map),
+            pl.BlockSpec((1, H, T, D), head_map),
         ] + tile_specs,
-        out_specs=pl.BlockSpec((1, T, H, D),
-                               lambda b, j, bt_s, nl: (b, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, T, D), head_map),
         scratch_shapes=[
             pltpu.VMEM((T, H), jnp.float32),
             pltpu.VMEM((T, H), jnp.float32),
-            pltpu.VMEM((T, H, D), jnp.float32),
+            pltpu.VMEM((H, T, D), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fused_decode_kernel, nsteps=nsteps, depth=depth,
-                          scale=scale, quantized=quantized),
+                          scale=scale, Kh=Kh, D=D, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, T, D), q.dtype),
         interpret=interpret,
-    )(bt_sub, nlive, q_seg.astype(jnp.int32), q_pos.astype(jnp.int32),
-      q, *tile_args)
+    )(bt_sub, nlive, q_seg.astype(jnp.int32)[:, :, None],
+      q_pos.astype(jnp.int32)[:, :, None], q.transpose(0, 2, 1, 3),
+      *tile_args)
+    return out.transpose(0, 2, 1, 3)

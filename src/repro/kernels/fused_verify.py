@@ -13,9 +13,9 @@ On top of ``kernels/paged_attention.paged_verify_attention`` this kernel
 adds the autotunable knobs searched by ``kernels/autotune.py``:
 
 ``bq``     query tile (rows of the packed query axis per grid step);
-``bk``     KV sub-tile — the pool is viewed as ``(N * f, bk, Kh, D)`` with
-           ``f = bs // bk`` (a reshape, not a copy), so one physical block
-           becomes ``f`` independently schedulable tiles;
+``bk``     KV sub-tile — the pool is viewed as ``(N * f, bk, Kh * D)``
+           with ``f = bs // bk`` (a reshape, not a copy), so one physical
+           block becomes ``f`` independently schedulable tiles;
 ``depth``  KV tiles fetched per grid step: the BlockSpec machinery issues
            the ``depth`` DMAs of step ``j+1`` while step ``j`` computes,
            i.e. block-table prefetch is double-buffered ``depth`` tiles
@@ -24,7 +24,8 @@ adds the autotunable knobs searched by ``kernels/autotune.py``:
 Trailing grid steps (the power-of-two padding of ``block_ids``) clamp
 their index map to the last *live* sub-block, so the revisit elides the
 DMA (same trick as ``paged_decode_attention``) and ``pl.when`` skips the
-compute — padding never costs a block read.
+compute — padding never costs a block read.  The block layout and the
+per-head tile math are those of ``kernels/fused_decode.py``.
 """
 
 from __future__ import annotations
@@ -37,101 +38,61 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG = -1e30
+from repro.kernels.fused_decode import attend_tile, finish_head, init_state
 
 
-def _fused_verify_kernel(ids_ref, owner_ref, nlive_ref,
+def _fused_verify_kernel(ids_ref, owner_ref, nlive_ref, q_lo_ref, q_hi_ref,
                          q_seg_ref, q_pos_ref, q_anc_ref, q_ref, *refs,
                          nsteps: int, depth: int, scale: float,
-                         quantized: bool = False):
+                         Kh: int, D: int, quantized: bool = False):
     group = 7 if quantized else 5
     tiles = refs[:group * depth]
     o_ref, m_ref, l_ref, acc_ref = refs[group * depth:]
+    H = q_ref.shape[0]
+    qi = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_state(m_ref, l_ref, acc_ref)
 
-    q_seg = q_seg_ref[...]                  # (BQ,)
+    q_seg = q_seg_ref[...]                  # (BQ, 1)
     q_pos = q_pos_ref[...]
-    q_anc = q_anc_ref[...]                  # (BQ,) ancestor bitmask
-    q_lo, q_hi = jnp.min(q_seg), jnp.max(q_seg)
-    q_pmax = jnp.max(q_pos)
+    q_anc = q_anc_ref[...]                  # (BQ, 1) ancestor bitmask
+
+    def q_of(h):
+        return q_ref[h].astype(jnp.float32) * scale         # (BQ, D)
 
     def _tile(i, pos_ref, seg_ref, node_ref, k_ref, v_ref, *sc_refs):
         t = j * depth + i
         owner = owner_ref[t]                # segment owning sub-block t
-        kv_pos = pos_ref[0]                 # (bk,)
-        kv_node = node_ref[0]               # (bk,) tree-node tag
-        # a pool slot is attendable iff its block is live (owner >= 0) and
-        # the slot itself holds committed/accepted KV (pool seg >= 0)
-        kv_seg = jnp.where(seg_ref[0] >= 0, owner, -1)
-        not_future = jnp.min(jnp.where(kv_seg >= 0, kv_pos,
-                                       jnp.iinfo(jnp.int32).max)) <= q_pmax
 
-        @pl.when((t < nlive_ref[0]) & (owner >= q_lo) & (owner <= q_hi)
-                 & (owner >= 0) & not_future)
+        # skip tiles past the live prefix and tiles owned by a segment
+        # outside this query tile's [lo, hi] range
+        @pl.when((t < nlive_ref[0]) & (owner >= 0)
+                 & (owner >= q_lo_ref[qi]) & (owner <= q_hi_ref[qi]))
         def _compute():
-            q = q_ref[...].astype(jnp.float32) * scale      # (BQ, H, D)
-            k = k_ref[0].astype(jnp.float32)                # (bk, Kh, D)
-            v = v_ref[0].astype(jnp.float32)
-            if quantized:
-                ks_ref, vs_ref = sc_refs
-                k = k * ks_ref[0][..., None]
-                v = v * vs_ref[0][..., None]
-            BQ, H, D = q.shape
-            bk, Kh, _ = k.shape
-            G = H // Kh
-            qg = q.reshape(BQ, Kh, G, D)
-            s = jax.lax.dot_general(
-                qg.transpose(1, 2, 0, 3).reshape(Kh, G * BQ, D),
-                k.transpose(1, 2, 0),
-                (((2,), (1,)), ((0,), (0,))))               # (Kh, G*BQ, bk)
-            s = s.reshape(Kh, G, BQ, bk).transpose(2, 0, 1, 3)
-            mask = (q_seg[:, None] == kv_seg[None, :]) \
-                & (kv_seg[None, :] >= 0) \
-                & (kv_pos[None, :] <= q_pos[:, None])       # (BQ, bk)
-            # tree-topology term (see kernels/verify_attention.py): -1 =
+            kv_pos = pos_ref[0]             # (1, bk)
+            kv_node = node_ref[0]           # (1, bk) tree-node tag
+            # a pool slot is attendable iff its block is live (owner >= 0)
+            # and the slot itself holds committed/accepted KV (seg >= 0)
+            kv_seg = jnp.where(seg_ref[0] >= 0, owner, -1)
+            mask = (q_seg == kv_seg) & (kv_seg >= 0) & (kv_pos <= q_pos)
+            # tree-topology term (see kernels/ref.tree_mask_term): -1 =
             # committed (always attendable), -2 = dead CoW duplicate
             # (never), n >= 0 = attendable iff bit n of the ancestor mask
-            nd = kv_node[None, :]
-            on_path = ((q_anc[:, None] >> jnp.clip(nd, 0, 31)) & 1) \
-                .astype(bool)
-            mask &= jnp.where(nd == -1, True,
-                              jnp.where(nd < -1, False, on_path))
-            s = jnp.where(mask[:, None, None, :], s, NEG)
-
-            m_prev = m_ref[...].reshape(BQ, Kh, G)
-            l_prev = l_ref[...].reshape(BQ, Kh, G)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-            m_safe = jnp.maximum(m_new, -1e29)
-            p = jnp.exp(s - m_safe[..., None])
-            p = jnp.where(mask[:, None, None, :], p, 0.0)
-            corr = jnp.where(jnp.isfinite(m_prev),
-                             jnp.exp(m_prev - m_safe), 0.0)
-            l_new = l_prev * corr + jnp.sum(p, axis=-1)
-            pv = jax.lax.dot_general(
-                p.transpose(1, 2, 0, 3).reshape(Kh, G * BQ, bk),
-                v.transpose(1, 0, 2),
-                (((2,), (1,)), ((0,), (0,))))               # (Kh, G*BQ, D)
-            pv = pv.reshape(Kh, G, BQ, D).transpose(2, 0, 1, 3)
-            acc_ref[...] = (acc_ref[...].reshape(BQ, Kh, G, D)
-                            * corr[..., None] + pv).reshape(BQ, Kh * G, D)
-            m_ref[...] = m_new.reshape(BQ, Kh * G)
-            l_ref[...] = l_new.reshape(BQ, Kh * G)
+            on_path = ((q_anc >> jnp.clip(kv_node, 0, 31)) & 1) == 1
+            mask &= (kv_node == -1) | ((kv_node >= 0) & on_path)
+            attend_tile(q_of, k_ref, v_ref, sc_refs, mask, m_ref, l_ref,
+                        acc_ref, Kh=Kh, D=D, G=H // Kh)
 
     for i in range(depth):
         _tile(i, *tiles[group * i:group * (i + 1)])
 
     @pl.when(j == nsteps - 1)
     def _finish():
-        l = l_ref[...]
-        o = acc_ref[...] / jnp.maximum(l, 1e-30)[..., None]
-        o = jnp.where((l > 0)[..., None], o, 0.0)
-        o_ref[...] = o.astype(o_ref.dtype)
+        for h in range(H):
+            o_ref[h] = finish_head(l_ref, acc_ref, h).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -173,11 +134,11 @@ def fused_paged_verify(q, k_pool, v_pool, pool_seg, pool_pos,
 
     # sub-tile view of the pool — a reshape of contiguous memory, no copy
     quantized = k_scale is not None
-    kp = k_pool.reshape(N * f, bk, Kh, D)
-    vp = v_pool.reshape(N * f, bk, Kh, D)
-    seg_p = pool_seg.astype(jnp.int32).reshape(N * f, bk)
-    pos_p = pool_pos.astype(jnp.int32).reshape(N * f, bk)
-    node_p = block_node.astype(jnp.int32).reshape(M * f, bk)
+    kp = k_pool.reshape(N * f, bk, Kh * D)
+    vp = v_pool.reshape(N * f, bk, Kh * D)
+    seg_p = pool_seg.astype(jnp.int32).reshape(N * f, 1, bk)
+    pos_p = pool_pos.astype(jnp.int32).reshape(N * f, 1, bk)
+    node_p = block_node.astype(jnp.int32).reshape(M * f, 1, bk)
     if quantized:
         ksp = k_scale.reshape(N * f, bk, Kh)
         vsp = v_scale.reshape(N * f, bk, Kh)
@@ -198,73 +159,75 @@ def fused_paged_verify(q, k_pool, v_pool, pool_seg, pool_pos,
     ids_sub = jnp.pad(ids_sub, (0, pad_t))
     owner_sub = jnp.pad(owner_sub, (0, pad_t), constant_values=-1)
 
-    Tq_p = int(np.ceil(Tq / bq) * bq)
-    qp = jnp.pad(q, ((0, Tq_p - Tq), (0, 0), (0, 0)))
+    nq = -(-Tq // bq)
+    Tq_p = nq * bq
+    qp = jnp.pad(q, ((0, Tq_p - Tq), (0, 0), (0, 0))).transpose(1, 0, 2)
 
-    def pad_i32(x, n):
-        return jnp.pad(x.astype(jnp.int32), (0, n), constant_values=-1)
-    q_seg_p = pad_i32(q_seg, Tq_p - Tq)
-    q_pos_p = pad_i32(q_pos, Tq_p - Tq)
-    q_anc_p = pad_i32(q_anc, Tq_p - Tq)
+    def pad_col(x):
+        return jnp.pad(x.astype(jnp.int32), (0, Tq_p - Tq),
+                       constant_values=-1)[:, None]
+    q_seg_p = pad_col(q_seg)
+    q_pos_p = pad_col(q_pos)
+    q_anc_p = pad_col(q_anc)
+    # per query tile segment range, for the in-kernel owner skip
+    q_lo = jnp.min(q_seg_p.reshape(nq, bq), axis=1)
+    q_hi = jnp.max(q_seg_p.reshape(nq, bq), axis=1)
 
     def clamp(j, i, nl):
         # trailing steps revisit the last live sub-block: DMA elided,
         # compute skipped in-kernel via t < nlive
         return jnp.minimum(j * depth + i, jnp.maximum(nl[0], 1) - 1)
 
-    def kv_map(i):
-        return lambda qi, j, ids_s, ow, nl: (ids_s[clamp(j, i, nl)], 0, 0, 0)
-
-    def slot_map(i):
-        return lambda qi, j, ids_s, ow, nl: (ids_s[clamp(j, i, nl)], 0)
+    def tile_map(i):
+        return lambda qi, j, ids_s, ow, nl, lo, hi: \
+            (ids_s[clamp(j, i, nl)], 0, 0)
 
     def node_map(i):
         # block_node is in *gathered* order, aligned with block_ids
-        return lambda qi, j, ids_s, ow, nl: (clamp(j, i, nl), 0)
+        return lambda qi, j, ids_s, ow, nl, lo, hi: (clamp(j, i, nl), 0, 0)
 
-    def q_map(qi, j, ids_s, ow, nl):
-        return (qi,)
+    def col_map(qi, j, ids_s, ow, nl, lo, hi):
+        return (qi, 0)
 
-    def sc_map(i):
-        return lambda qi, j, ids_s, ow, nl: (ids_s[clamp(j, i, nl)], 0, 0)
+    def head_map(qi, j, ids_s, ow, nl, lo, hi):
+        return (0, qi, 0)
 
     tile_specs = []
     tile_args = []
     for i in range(depth):
-        tile_specs += [pl.BlockSpec((1, bk), slot_map(i)),
-                       pl.BlockSpec((1, bk), slot_map(i)),
-                       pl.BlockSpec((1, bk), node_map(i)),
-                       pl.BlockSpec((1, bk, Kh, D), kv_map(i)),
-                       pl.BlockSpec((1, bk, Kh, D), kv_map(i))]
+        tile_specs += [pl.BlockSpec((1, 1, bk), tile_map(i)),
+                       pl.BlockSpec((1, 1, bk), tile_map(i)),
+                       pl.BlockSpec((1, 1, bk), node_map(i)),
+                       pl.BlockSpec((1, bk, Kh * D), tile_map(i)),
+                       pl.BlockSpec((1, bk, Kh * D), tile_map(i))]
         tile_args += [pos_p, seg_p, node_p, kp, vp]
         if quantized:
-            tile_specs += [pl.BlockSpec((1, bk, Kh), sc_map(i)),
-                           pl.BlockSpec((1, bk, Kh), sc_map(i))]
+            tile_specs += [pl.BlockSpec((1, bk, Kh), tile_map(i)),
+                           pl.BlockSpec((1, bk, Kh), tile_map(i))]
             tile_args += [ksp, vsp]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(Tq_p // bq, nsteps),
+        num_scalar_prefetch=5,
+        grid=(nq, nsteps),
         in_specs=[
-            pl.BlockSpec((bq,), q_map),
-            pl.BlockSpec((bq,), q_map),
-            pl.BlockSpec((bq,), q_map),
-            pl.BlockSpec((bq, H, D), lambda qi, j, ids_s, ow, nl:
-                         (qi, 0, 0)),
+            pl.BlockSpec((bq, 1), col_map),
+            pl.BlockSpec((bq, 1), col_map),
+            pl.BlockSpec((bq, 1), col_map),
+            pl.BlockSpec((H, bq, D), head_map),
         ] + tile_specs,
-        out_specs=pl.BlockSpec((bq, H, D), lambda qi, j, ids_s, ow, nl:
-                               (qi, 0, 0)),
+        out_specs=pl.BlockSpec((H, bq, D), head_map),
         scratch_shapes=[
             pltpu.VMEM((bq, H), jnp.float32),
             pltpu.VMEM((bq, H), jnp.float32),
-            pltpu.VMEM((bq, H, D), jnp.float32),
+            pltpu.VMEM((H, bq, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(_fused_verify_kernel, nsteps=nsteps, depth=depth,
-                          scale=scale, quantized=quantized),
+                          scale=scale, Kh=Kh, D=D, quantized=quantized),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Tq_p, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((H, Tq_p, D), q.dtype),
         interpret=interpret,
-    )(ids_sub, owner_sub, nlive, q_seg_p, q_pos_p, q_anc_p, qp, *tile_args)
-    return out[:Tq]
+    )(ids_sub, owner_sub, nlive, q_lo, q_hi, q_seg_p, q_pos_p, q_anc_p, qp,
+      *tile_args)
+    return out.transpose(1, 0, 2)[:Tq]

@@ -1,8 +1,9 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: False on TPU (compiled Mosaic), True
-elsewhere (kernel body executed in Python on CPU — how this repo validates
-TPU kernels without TPU hardware)."""
+``interpret`` defaults to :func:`interpret_mode`: False on TPU (compiled
+Mosaic), True on the CPU (kernel body executed by the Pallas interpreter —
+how this repo validates TPU kernels without TPU hardware).  Any other
+backend is an error: there is no silent interpreted path on a device."""
 
 from __future__ import annotations
 
@@ -19,14 +20,22 @@ from repro.kernels.paged_attention import (
 from repro.kernels.verify_attention import verify_attention as _verify
 
 
-def _auto_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted on this backend."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas kernels compile for the TPU and run interpreted on the "
+        f"CPU; backend {backend!r} has neither path")
 
 
 def verify_attention(q, k, v, q_seg, q_pos, kv_seg, kv_pos, *,
                      bq: int = 128, bk: int = 128, interpret=None):
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     return _verify(q, k, v, q_seg, q_pos, kv_seg, kv_pos, bq=bq, bk=bk,
                    interpret=interpret)
 
@@ -34,13 +43,13 @@ def verify_attention(q, k, v, q_seg, q_pos, kv_seg, kv_pos, *,
 def flash_attention(q, k, v, *, window: int = 0, bq: int = 128,
                     bk: int = 128, interpret=None):
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     return _flash(q, k, v, window=window, bq=bq, bk=bk, interpret=interpret)
 
 
 def decode_attention(q, k, v, lengths, *, bk=None, interpret=None):
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     return _decode(q, k, v, lengths, bk=bk, interpret=interpret)
 
 
@@ -48,7 +57,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths,
                            k_scale=None, v_scale=None, *,
                            interpret=None):
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     return _paged_decode(q, k_pool, v_pool, block_tables, lengths,
                          k_scale, v_scale, interpret=interpret)
 
@@ -58,7 +67,7 @@ def paged_verify_attention(q, k_pool, v_pool, pool_seg, pool_pos,
                            k_scale=None, v_scale=None, *,
                            bq: int = 128, interpret=None):
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     return _paged_verify(q, k_pool, v_pool, pool_seg, pool_pos,
                          q_seg, q_pos, block_ids, block_owner,
                          k_scale=k_scale, v_scale=v_scale,
@@ -89,7 +98,7 @@ def fused_paged_verify(q, k_pool, v_pool, pool_seg, pool_pos,
     ``autotune.FusedConfig``) pins the tile shapes; None consults the
     autotune cache with the default fallback."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     shape = "tree" if block_node is not None else "linear"
     cfg = _resolve_config("verify", q, k_pool, gamma_max, shape, config)
     return _fused_verify(q, k_pool, v_pool, pool_seg, pool_pos,
@@ -106,7 +115,7 @@ def fused_paged_decode(q, k_pool, v_pool, pool_seg, pool_pos,
     """Single-launch multi-token paged decode (kernels/fused_decode.py)
     with block-table prefetch double-buffered against tile compute."""
     if interpret is None:
-        interpret = _auto_interpret()
+        interpret = interpret_mode()
     cfg = _resolve_config("decode", q, k_pool, gamma_max, "linear", config)
     return _fused_decode(q, k_pool, v_pool, pool_seg, pool_pos,
                          q_seg, q_pos, block_tables, k_scale, v_scale,

@@ -25,6 +25,15 @@ from typing import List, Tuple
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with ``Auto`` axes: the rule tables place arrays
+    through ``with_sharding_constraint`` (distributed/sharding.py), which
+    accepts only ``Auto`` axes, while ``make_mesh`` defaults to
+    ``Explicit`` ones."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, replicas: int = 1):
@@ -34,15 +43,15 @@ def make_production_mesh(*, multi_pod: bool = False, replicas: int = 1):
     if replicas > 1:
         shape = (replicas,) + shape
         axes = ("replica",) + axes
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh(data: int = 1, model: int = 1, replicas: int = 1):
     """Small mesh over whatever devices exist (CPU tests / examples)."""
     if replicas > 1:
-        return jax.make_mesh((replicas, data, model),
-                             ("replica", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _auto_mesh((replicas, data, model),
+                          ("replica", "data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def carve_replica_axis(devices: np.ndarray, axis_names: Tuple[str, ...]

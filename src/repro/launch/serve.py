@@ -6,14 +6,16 @@
         [--kv-layout paged|dense] [--block-size 16] \
         [--replicas 2 --router-policy lot]
 
-Builds the heterogeneous SSM zoo + LLM (reduced configs on CPU; the same
-code paths drive full configs on a pod, where ``--mesh`` places the LLM on
-the `model` axis via pjit and each SSM replica on a dedicated data slice —
-see DESIGN.md §6), then drives the continuous-batching scheduler loop
-until the request stream drains.  ``--arrival-rate`` turns the workload
-into a streaming Poisson arrival process (requests/sec on the sim clock);
-without it every request arrives at t=0.  ``--scheduler static`` keeps the
-seed-style gang-scheduled cohort baseline for comparison.
+Builds the heterogeneous SSM zoo + LLM (reduced configs by default, for
+CPU runs; ``chip_smoke.py`` at the repo root hands :func:`build_server`
+the published LLaMA widths and runs them on one TPU chip), then drives
+the continuous-batching scheduler loop until the request stream drains.
+Everything runs in one process on its first device: replicas are not yet
+placed on chips or mesh slices of their own.  ``--arrival-rate`` turns
+the workload into a streaming Poisson arrival process (requests/sec on
+the sim clock); without it every request arrives at t=0.  ``--scheduler
+static`` keeps the seed-style gang-scheduled cohort baseline for
+comparison.
 
 ``--replicas N`` serves the stream through N independent engine replicas
 behind a router (serving/router.py): ``--capacity`` and ``--kv-budget``
@@ -28,6 +30,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import os
 
 import jax
 
@@ -45,19 +48,53 @@ from repro.serving.router import (CLASS_KV_WEIGHTS, Router, RouterConfig,
                                   class_engine_config, parse_replica_classes)
 
 
-def build_zoo(vocab: int, seed: int = 0, n_ssms: int = 3):
-    """Reduced-scale LLM + heterogeneous SSM zoo (shape-faithful families
-    of the paper's LLaMA 68M..1.4B lineup)."""
-    key = jax.random.PRNGKey(seed)
-    cfg_llm = reduced(spin_llama.LLAMA_7B, d_model=96, n_heads=4,
-                      n_kv_heads=4, vocab_size=vocab, n_layers=4)
-    llm = sd.Bundle(cfg_llm, T.init_params(cfg_llm, key))
-    dims = [(32, 1), (48, 2), (64, 2), (96, 3), (96, 4)][:n_ssms]
-    ssms = []
-    for i, (d, L) in enumerate(dims):
-        c = reduced(spin_llama.SSM_ZOO[min(i, 4)], d_model=d, n_heads=4,
-                    n_kv_heads=4, vocab_size=vocab, n_layers=L)
-        ssms.append(sd.Bundle(c, T.init_params(c, jax.random.PRNGKey(i + 1))))
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is
+# unset: one fixed, git-ignored directory of the checkout (the path is
+# part of the cache key, so it must not move between runs)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache")
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is
+    (JAX reads it itself); otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`.  Call before the first compile: JAX
+    settles on a cache once per process."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.normpath(COMPILE_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_zoo(vocab: int, seed: int = 0, n_ssms: int = 3,
+              llm_cfg=None, ssm_cfgs=None):
+    """LLM + heterogeneous SSM zoo.  By default a reduced-scale LLaMA-7B
+    and ``n_ssms`` reduced drafters shape-faithful to the paper's LLaMA
+    68M..1.4B lineup (CPU runs); ``llm_cfg``/``ssm_cfgs`` build the given
+    configs instead, e.g. the published widths.  Weights are random: the
+    LLM's from ``seed``, drafter i's from key i + 1.  Every model must
+    have the ``vocab``-token vocabulary, since drafts are verified token
+    for token."""
+    if llm_cfg is None:
+        llm_cfg = reduced(spin_llama.LLAMA_7B, d_model=96, n_heads=4,
+                          n_kv_heads=4, vocab_size=vocab, n_layers=4)
+    if ssm_cfgs is None:
+        dims = [(32, 1), (48, 2), (64, 2), (96, 3), (96, 4)][:n_ssms]
+        ssm_cfgs = [reduced(spin_llama.SSM_ZOO[min(i, 4)], d_model=d,
+                            n_heads=4, n_kv_heads=4, vocab_size=vocab,
+                            n_layers=L)
+                    for i, (d, L) in enumerate(dims)]
+    for c in [llm_cfg, *ssm_cfgs]:
+        if c.vocab_size != vocab:
+            raise ValueError(f"{c.name} has vocab {c.vocab_size}, the zoo "
+                             f"serves vocab {vocab}")
+    llm = sd.Bundle(llm_cfg, T.init_params(llm_cfg, jax.random.PRNGKey(seed)))
+    ssms = [sd.Bundle(c, T.init_params(c, jax.random.PRNGKey(i + 1)))
+            for i, c in enumerate(ssm_cfgs)]
     return llm, ssms
 
 
@@ -177,13 +214,12 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["on", "off"],
                     help="route the paged decode/verify hot path through "
                          "the fused single-launch Pallas kernels "
-                         "(kernels/fused_decode.py, fused_verify.py), "
-                         "tile shapes resolved from the autotune cache "
-                         "(results/TUNE_cache.json, safe default on a "
-                         "cold miss); off keeps the gather + "
-                         "paged-attention path bit-identically (needs "
-                         "--kv-layout paged; falls back with a warning "
-                         "otherwise)")
+                         "(kernels/fused_decode.py, fused_verify.py) at "
+                         "the default tile shapes "
+                         "(kernels/autotune.DEFAULT_CONFIG); off keeps "
+                         "the gather + paged-attention path "
+                         "bit-identically (needs --kv-layout paged; falls "
+                         "back with a warning otherwise)")
     ap.add_argument("--kv-dtype", default="bf16",
                     choices=["bf16", "int8", "fp8"],
                     help="paged-KV block storage dtype: bf16 stores the "
@@ -269,7 +305,12 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main(argv=None):
+def build_server(argv=None, zoo=None):
+    """Parse ``argv`` and build what :func:`main` runs: returns
+    ``(server, requests, args)`` where ``server`` is a ``SpinEngine`` or,
+    for a fleet, a ``Router``, with ``requests`` already submitted.
+    ``zoo`` is an ``(llm, ssms)`` pair from :func:`build_zoo`; by default
+    the reduced zoo at ``--vocab`` is built."""
     ap = build_parser()
     args = ap.parse_args(argv)
     # flag translation + cross-flag validation live in the configs'
@@ -339,7 +380,11 @@ def main(argv=None):
                 burst_len=span / 4.0, seed=args.seed ^ 0xB5B)
         arrival_rate = None
 
-    llm, ssms = build_zoo(args.vocab, args.seed, args.n_ssms)
+    if zoo is not None and zoo[0].cfg.vocab_size != args.vocab:
+        ap.error(f"--vocab {args.vocab} differs from the zoo's vocab "
+                 f"{zoo[0].cfg.vocab_size}")
+    use_compile_cache()
+    llm, ssms = zoo or build_zoo(args.vocab, args.seed, args.n_ssms)
     reqs = make_workload(args.dataset, args.requests, args.vocab,
                          seed=args.seed, scale=args.scale,
                          arrival_rate=arrival_rate,
@@ -382,13 +427,17 @@ def main(argv=None):
             kvs = split_evenly(args.kv_budget, n_eng)
         engines = [make_engine(caps[i], kvs[i], args.seed, classes[i])
                    for i in range(n_eng)]
-        router = Router(engines, rcfg)
-        router.submit(reqs)
-        stats = router.run(max_slots=args.max_slots)
+        server = Router(engines, rcfg)
+        server.submit(reqs)
     else:
-        eng = make_engine(capacity, args.kv_budget, args.seed, classes[0])
-        eng.add_requests(reqs)
-        stats = eng.run(max_slots=args.max_slots)
+        server = make_engine(capacity, args.kv_budget, args.seed, classes[0])
+        server.add_requests(reqs)
+    return server, reqs, args
+
+
+def main(argv=None):
+    server, _, args = build_server(argv)
+    stats = server.run(max_slots=args.max_slots)
     print(json.dumps(stats, indent=2, default=str))
     return stats
 

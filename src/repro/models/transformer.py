@@ -51,12 +51,18 @@ class Opts:
 def _attn_spec(cfg: C.ModelConfig, is_moe: bool) -> Dict[str, Any]:
     d, hd = cfg.d_model, cfg.hd
     nq, nkv = cfg.n_heads, cfg.n_kv_heads
+    # explicit 1/sqrt(fan_in): the default reads fan_in off shape[-2],
+    # a head count for these head-split leaves.  Attention logits ~100x
+    # too large make a deep random model chaotic: over 16 layers f32
+    # reduction-order noise grows to whole logits, and no two attention
+    # paths agree on a single token.
+    s_in, s_out = d ** -0.5, (nq * hd) ** -0.5
     s: Dict[str, Any] = {
         "ln1": P((d,), ("embed",), init="zeros"),
-        "wq": P((d, nq, hd), ("embed", "heads", "head_dim")),
-        "wk": P((d, nkv, hd), ("embed", "kv_heads", "head_dim")),
-        "wv": P((d, nkv, hd), ("embed", "kv_heads", "head_dim")),
-        "wo": P((nq, hd, d), ("heads", "head_dim", "embed")),
+        "wq": P((d, nq, hd), ("embed", "heads", "head_dim"), scale=s_in),
+        "wk": P((d, nkv, hd), ("embed", "kv_heads", "head_dim"), scale=s_in),
+        "wv": P((d, nkv, hd), ("embed", "kv_heads", "head_dim"), scale=s_in),
+        "wo": P((nq, hd, d), ("heads", "head_dim", "embed"), scale=s_out),
         "ln2": P((d,), ("embed",), init="zeros"),
     }
     if cfg.qkv_bias:

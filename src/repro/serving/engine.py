@@ -133,11 +133,10 @@ class EngineConfig:
     spec_branch: int = 2
     # fused speculative-step Pallas kernels (kernels/fused_decode.py /
     # fused_verify.py): "on" streams KV straight from the paged pool in a
-    # single launch per attention site, with tile shapes resolved once at
-    # engine construction from the autotune cache
-    # (results/TUNE_cache.json, safe default on a cold miss); "off" keeps
-    # the PR-6 gather + paged-kernel path bit-identically.  Requires the
-    # paged layout; "on" under a dense fallback warns and stays unfused.
+    # single launch per attention site, with the default tile shapes
+    # (kernels/autotune.DEFAULT_CONFIG); "off" keeps the gather +
+    # paged-kernel path bit-identically.  Requires the paged layout; "on"
+    # under a dense fallback warns and stays unfused.
     fused_kernels: str = "off"
     # paged-KV block storage dtype (kernels/quant.py): "bf16" stores the
     # model's compute dtype (bit-identical default); "int8"/"fp8" store
@@ -275,16 +274,17 @@ class SpinEngine:
                 f"{self.gamma_max + min(ecfg.spec_branch, self.gamma_max)}"
                 f"); lower --gamma-max or --spec-branch")
         # fused Pallas kernels stream KV straight out of the paged block
-        # pool, so they require the paged layout; resolve each bundle's
-        # tile config ONCE here (autotune-cache lookup with the safe
-        # default on a cold miss) so dispatch never tunes implicitly and
-        # every jit trace sees a stable static config
+        # pool, so they require the paged layout.  Every site takes the
+        # default tile config: the autotune cache lives in untracked
+        # results/ and holds interpret-mode timings, so the served path
+        # must not depend on it.  The config is static under jit.
         self.fused = ecfg.fused_kernels == "on" and self.paged
         if ecfg.fused_kernels == "on" and not self.paged:
             warnings.warn(
                 "fused_kernels='on' requires the paged KV layout; "
                 "falling back to the unfused attention path",
                 stacklevel=2)
+        self.fused_cfg = autotune.DEFAULT_CONFIG if self.fused else None
         # quantized blocks live in the paged pool's block/scale layout;
         # the dense grids have no sidecar plumbing, so a dense fallback
         # reverts to the compute dtype (mirrors the fused fallback above)
@@ -294,19 +294,6 @@ class SpinEngine:
                 f"kv_dtype={ecfg.kv_dtype!r} requires the paged KV "
                 "layout; falling back to bf16 (unquantized) KV",
                 stacklevel=2)
-        shape = "tree" if self.tree else "linear"
-
-        def _fused_cfg(kind, b, s="linear"):
-            if not self.fused:
-                return None
-            return autotune.get_config(
-                kind, H=b.cfg.n_heads, Kh=b.cfg.n_kv_heads, D=b.cfg.hd,
-                gamma_max=self.gamma_max, block_size=ecfg.block_size,
-                shape=s, kv_dtype=self.kv_dtype)
-
-        self.fused_llm_decode = _fused_cfg("decode", llm)
-        self.fused_llm_verify = _fused_cfg("verify", llm, shape)
-        self.fused_ssm_decode = [_fused_cfg("decode", b) for b in self.ssms]
         # each extra branch needs a pool row to draft/verify through;
         # scheduler capacity (concurrent requests) stays ecfg.capacity
         row_mult = self.branches
@@ -605,7 +592,7 @@ class SpinEngine:
             bt = self.llm_pool.row_table(rid)
             logits, cache = self.llm.append_paged(
                 self.llm_pool.cache, jnp.asarray(toks), lengths,
-                jnp.asarray(segs), bt, self.fused_llm_decode)
+                jnp.asarray(segs), bt, self.fused_cfg)
             self.llm_pool.cache = cache
         else:
             one = self.llm_pool.row_cache(rid)
@@ -954,7 +941,7 @@ class SpinEngine:
             bt, _ = pool.block_table_array()
             cand, _, cache = sd.draft(b, pool.cache, tok, lengths,
                                       width, k, block_tables=bt,
-                                      fused_cfg=self.fused_ssm_decode[j])
+                                      fused_cfg=self.fused_cfg)
             pool.cache = cache
             return np.asarray(cand)
         cand, _, cache = sd.draft(b, pool.cache, tok, lengths,
@@ -1030,7 +1017,7 @@ class SpinEngine:
         bt, _ = pool.block_table_array()
         cand, cache = sd.draft_tree(b, pool.cache, tok, lengths, width,
                                     ranks, block_tables=bt,
-                                    fused_cfg=self.fused_ssm_decode[j])
+                                    fused_cfg=self.fused_cfg)
         pool.cache = cache
         for brid in forked:
             pool.evict(brid)
@@ -1148,7 +1135,7 @@ class SpinEngine:
                 bt, _ = self.llm_pool.block_table_array()
                 logits, cache = self.llm.decode_paged(
                     self.llm_pool.cache, inp, lengths, bt,
-                    self.fused_llm_decode)
+                    self.fused_cfg)
             else:
                 logits, cache = self.llm.decode(self.llm_pool.cache, inp,
                                                 lengths)
@@ -1245,7 +1232,7 @@ class SpinEngine:
                 bt, _ = pool.block_table_array()
                 _, pool.cache = self.ssms[j].decode_paged(
                     pool.cache, jnp.asarray(outs_j), pl + 1, bt,
-                    self.fused_ssm_decode[j])
+                    self.fused_cfg)
                 pool.invalidate_span(
                     pl + 2 + jnp.asarray(nacc_j, jnp.int32),
                     pl + W + 3, W=W + 1)
@@ -1299,7 +1286,7 @@ class SpinEngine:
                     jnp.asarray(q_seg), jnp.asarray(q_rows), bt,
                     jnp.asarray(ids_np), jnp.asarray(owner_np),
                     jnp.asarray(q_anc), jnp.asarray(block_node),
-                    self.fused_llm_verify)
+                    self.fused_cfg)
             else:
                 q_rows, q_pos, q_seg = D.build_query_layout(lens_np, W)
                 logits, cache = self.llm.verify_paged(
@@ -1307,7 +1294,7 @@ class SpinEngine:
                     jnp.asarray(q_pos.astype(np.int32)),
                     jnp.asarray(q_seg), jnp.asarray(q_rows), bt,
                     jnp.asarray(ids_np), jnp.asarray(owner_np),
-                    self.fused_llm_verify)
+                    self.fused_cfg)
             self.llm_pool.cache = cache
             return logits[0].reshape(N, W + 1, -1)
         lens_np = np.maximum(np.asarray(lengths), 1)
@@ -1435,6 +1422,9 @@ class SpinEngine:
                               else 0),
             "spec_shape": "tree" if self.tree else "linear",
             "fused_kernels": "on" if self.fused else "off",
+            # tile config of every fused attention site (None = unfused)
+            "fused_config": (dataclasses.asdict(self.fused_cfg)
+                             if self.fused else None),
             "kv_dtype": self.kv_dtype,
             "spec_branches": self.branches,
             "verify_tokens": self.verify_tokens_total,

@@ -4,6 +4,7 @@
 autotune-cache behavior (cold-miss fallback, populate/consult roundtrip),
 and engine-level bit-identity of ``--fused-kernels on`` vs ``off``."""
 
+import dataclasses
 import warnings
 
 import jax
@@ -17,7 +18,7 @@ from repro.configs import registry
 from repro.core import spec_decode as sd
 from repro.core.selector import LBSS, SelectorConfig
 from repro.data.workloads import make_workload
-from repro.kernels import autotune, ref
+from repro.kernels import autotune, ops, ref
 from repro.kernels.fused_decode import fused_paged_decode
 from repro.kernels.fused_verify import fused_paged_verify
 from repro.models import transformer as T
@@ -234,6 +235,19 @@ def test_fused_config_is_jit_cache_key():
     assert a != autotune.FusedConfig(bq=8, bk=0, depth=1)
 
 
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Kernels compile on the TPU and interpret on the CPU; any other
+    backend is refused instead of silently running interpreted."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match=repr(backend)):
+            ops.interpret_mode()
+    else:
+        assert ops.interpret_mode() is interpret
+
+
 # ------------------------------------------------- engine bit-identity ----
 
 @pytest.fixture(scope="module")
@@ -286,6 +300,10 @@ def test_fused_engine_bit_identical(models, shape):
     on = _run(llm, ssms, spec_shape=shape, fused_kernels="on")
     assert off.stats()["fused_kernels"] == "off"
     assert on.stats()["fused_kernels"] == "on"
+    # the served path runs the default tiles, never the untracked cache
+    assert off.stats()["fused_config"] is None
+    assert on.stats()["fused_config"] == dataclasses.asdict(
+        autotune.DEFAULT_CONFIG)
     _same_trace(off, on)
 
 
@@ -298,7 +316,7 @@ def test_fused_on_dense_layout_warns_and_falls_back(models):
             gamma=3, max_len=128, capacity=4, kv_layout="dense",
             fused_kernels="on"))
     assert not eng.fused
-    assert eng.fused_llm_verify is None
+    assert eng.fused_cfg is None
 
 
 def test_engine_rejects_unknown_fused_kernels(models):
