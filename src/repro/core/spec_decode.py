@@ -30,18 +30,27 @@ from repro.models import transformer as T
 
 @dataclasses.dataclass
 class Bundle:
-    """A model + jitted entry points (one per (B, T) shape, cached by jit)."""
+    """A model + jitted entry points (one per (B, T) shape, cached by jit).
+
+    Each entry point jits a function named after its method, so its
+    programs carry a stable name in a profile: ``jit_prefill``,
+    ``jit_decode``, ``jit_append``, ``jit_append_paged``,
+    ``jit_decode_paged``, ``jit_verify_paged`` and
+    ``jit_verify_paged_tree``."""
     cfg: C.ModelConfig
     params: dict
 
     def __post_init__(self):
-        self._prefill = jax.jit(
-            lambda p, toks, lengths, max_len: T.prefill(
-                p, self.cfg, tokens=toks, lengths=lengths, max_len=max_len),
-            static_argnames=("max_len",))
-        self._decode = jax.jit(
-            lambda p, cache, toks, lengths: T.decode_step(
-                p, self.cfg, cache, tokens=toks, lengths=lengths))
+        def prefill(p, toks, lengths, max_len):
+            return T.prefill(p, self.cfg, tokens=toks, lengths=lengths,
+                             max_len=max_len)
+
+        def decode(p, cache, toks, lengths):
+            return T.decode_step(p, self.cfg, cache, tokens=toks,
+                                 lengths=lengths)
+
+        self._prefill = jax.jit(prefill, static_argnames=("max_len",))
+        self._decode = jax.jit(decode)
         # paged entry points are cached per fused_cfg (None = XLA gather
         # path; a kernels/autotune.FusedConfig = fused Pallas path) — the
         # config is static under jit, so each distinct config is its own
@@ -64,9 +73,10 @@ class Bundle:
         marks bucket-padding tokens with -1 so their KV writes land
         invalidated and one trace serves every chunk width bucket."""
         if self._append is None:
-            self._append = jax.jit(
-                lambda p, c, t, l, s: T.decode_step(
-                    p, self.cfg, c, tokens=t, lengths=l, segments=s))
+            def append(p, c, t, l, s):
+                return T.decode_step(p, self.cfg, c, tokens=t, lengths=l,
+                                     segments=s)
+            self._append = jax.jit(append)
         return self._append(self.params, cache, toks, lengths, segments)
 
     def append_paged(self, cache, toks, lengths, segments, block_tables,
@@ -76,10 +86,12 @@ class Bundle:
         context blocks (see serving/paged.decode_step_paged)."""
         if fused_cfg not in self._append_paged:
             from repro.serving.paged import decode_step_paged
-            self._append_paged[fused_cfg] = jax.jit(
-                lambda p, c, t, l, s, bt: decode_step_paged(
+
+            def append_paged(p, c, t, l, s, bt):
+                return decode_step_paged(
                     p, self.cfg, c, tokens=t, lengths=l, segments=s,
-                    block_tables=bt, fused_cfg=fused_cfg))
+                    block_tables=bt, fused_cfg=fused_cfg)
+            self._append_paged[fused_cfg] = jax.jit(append_paged)
         return self._append_paged[fused_cfg](self.params, cache, toks,
                                              lengths, segments, block_tables)
 
@@ -90,10 +102,12 @@ class Bundle:
         step without retracing."""
         if fused_cfg not in self._decode_paged:
             from repro.serving.paged import decode_step_paged
-            self._decode_paged[fused_cfg] = jax.jit(
-                lambda p, c, t, l, bt: decode_step_paged(
+
+            def decode_paged(p, c, t, l, bt):
+                return decode_step_paged(
                     p, self.cfg, c, tokens=t, lengths=l, block_tables=bt,
-                    fused_cfg=fused_cfg))
+                    fused_cfg=fused_cfg)
+            self._decode_paged[fused_cfg] = jax.jit(decode_paged)
         return self._decode_paged[fused_cfg](self.params, cache, toks,
                                              lengths, block_tables)
 
@@ -103,11 +117,13 @@ class Bundle:
         paged block pool (no flat packed copy)."""
         if fused_cfg not in self._verify_paged:
             from repro.serving.paged import verify_step_paged
-            self._verify_paged[fused_cfg] = jax.jit(
-                lambda p, c, t, pos, seg, qr, bt, ids, ow: verify_step_paged(
+
+            def verify_paged(p, c, t, pos, seg, qr, bt, ids, ow):
+                return verify_step_paged(
                     p, self.cfg, c, tokens=t, positions=pos, segments=seg,
                     q_rows=qr, block_tables=bt, block_ids=ids,
-                    block_owner=ow, fused_cfg=fused_cfg))
+                    block_owner=ow, fused_cfg=fused_cfg)
+            self._verify_paged[fused_cfg] = jax.jit(verify_paged)
         return self._verify_paged[fused_cfg](
             self.params, cache, tokens, positions, segments, q_rows,
             block_tables, block_ids, block_owner)
@@ -120,13 +136,15 @@ class Bundle:
         pass scores every root-to-leaf path of a token tree."""
         if fused_cfg not in self._verify_paged_tree:
             from repro.serving.paged import verify_step_paged
-            self._verify_paged_tree[fused_cfg] = jax.jit(
-                lambda p, c, t, pos, seg, qr, bt, ids, ow, anc, node:
-                verify_step_paged(
+
+            def verify_paged_tree(p, c, t, pos, seg, qr, bt, ids, ow, anc,
+                                  node):
+                return verify_step_paged(
                     p, self.cfg, c, tokens=t, positions=pos, segments=seg,
                     q_rows=qr, block_tables=bt, block_ids=ids,
                     block_owner=ow, q_anc=anc, block_node=node,
-                    fused_cfg=fused_cfg))
+                    fused_cfg=fused_cfg)
+            self._verify_paged_tree[fused_cfg] = jax.jit(verify_paged_tree)
         return self._verify_paged_tree[fused_cfg](
             self.params, cache, tokens, positions, segments, q_rows,
             block_tables, block_ids, block_owner, q_anc, block_node)

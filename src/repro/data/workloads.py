@@ -145,6 +145,11 @@ class Request:
     # ``emitted``), the deadline-attainment source: token j met its SLO
     # iff token_times[j] <= slo.token_deadline(arrival, j)
     token_times: Optional[List[float]] = None
+    # host clock (time.perf_counter) at the first admission: when the row
+    # was granted and prefill was about to start, and when the prefill's
+    # first token had been read back; a re-admission keeps both
+    host_admitted: Optional[float] = None
+    host_first_token: Optional[float] = None
 
     @property
     def prompt_len(self) -> int:

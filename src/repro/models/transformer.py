@@ -322,54 +322,60 @@ def _attn_block(p, x, cfg, opts, *, positions, segments, kv_cache,
                                   attend over the whole cache grid.
     """
     B, S, d = x.shape
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _project_qkv(p, h, cfg, positions)
-    segs = segments if segments is not None else jnp.zeros(
-        (B, S), jnp.int32)
+    with jax.named_scope("attention"):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _project_qkv(p, h, cfg, positions)
+        segs = segments if segments is not None else jnp.zeros(
+            (B, S), jnp.int32)
 
-    new_cache = None
-    if attn_override is not None:
-        # SPIN packed verification: override handles attention + write-back
-        o, new_cache = attn_override(q, k, v, positions, segs, kv_cache,
-                                     cfg, opts)
-    elif kv_cache is not None:
-        bidx = jnp.arange(B)[:, None]
-        kc = kv_cache["k"].at[bidx, write_idx].set(k.astype(kv_cache["k"].dtype))
-        vc = kv_cache["v"].at[bidx, write_idx].set(v.astype(kv_cache["v"].dtype))
-        pc = kv_cache["pos"].at[bidx, write_idx].set(positions)
-        sc = kv_cache["seg"].at[bidx, write_idx].set(segs)
-        new_cache = {"k": kc, "v": vc, "pos": pc, "seg": sc}
+        new_cache = None
+        if attn_override is not None:
+            # SPIN packed verification: override handles attention and
+            # the KV write-back
+            o, new_cache = attn_override(q, k, v, positions, segs, kv_cache,
+                                         cfg, opts)
+        elif kv_cache is not None:
+            bidx = jnp.arange(B)[:, None]
+            kc = kv_cache["k"].at[bidx, write_idx].set(
+                k.astype(kv_cache["k"].dtype))
+            vc = kv_cache["v"].at[bidx, write_idx].set(
+                v.astype(kv_cache["v"].dtype))
+            pc = kv_cache["pos"].at[bidx, write_idx].set(positions)
+            sc = kv_cache["seg"].at[bidx, write_idx].set(segs)
+            new_cache = {"k": kc, "v": vc, "pos": pc, "seg": sc}
 
-    if attn_override is not None:
-        pass
-    elif opts.attn_stub:
-        # flash-accounting stub: keeps q/k/v projections + output shape,
-        # removes the attention math (see benchmarks/perf_hillclimb.py)
-        o = q * (jnp.mean(v) + jnp.mean(k))
-    elif kv_cache is not None and attend_cache:
-        o = attention(q, new_cache["k"], new_cache["v"],
-                      q_positions=positions, kv_positions=new_cache["pos"],
-                      q_segments=segs, kv_segments=new_cache["seg"],
-                      window=cfg.sliding_window, q_block=opts.q_block)
-    else:
-        o = attention(q, k, v, q_positions=positions, kv_positions=positions,
-                      q_segments=segments, kv_segments=segments,
-                      window=cfg.sliding_window, q_block=opts.q_block)
-    o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
-    x = x + o
-    x = constrain(x, "batch", "seq", "act_embed")
+        if attn_override is not None:
+            pass
+        elif opts.attn_stub:
+            # flash-accounting stub: keeps q/k/v projections + output shape,
+            # removes the attention math (see benchmarks/perf_hillclimb.py)
+            o = q * (jnp.mean(v) + jnp.mean(k))
+        elif kv_cache is not None and attend_cache:
+            o = attention(q, new_cache["k"], new_cache["v"],
+                          q_positions=positions, kv_positions=new_cache["pos"],
+                          q_segments=segs, kv_segments=new_cache["seg"],
+                          window=cfg.sliding_window, q_block=opts.q_block)
+        else:
+            o = attention(q, k, v, q_positions=positions,
+                          kv_positions=positions, q_segments=segments,
+                          kv_segments=segments, window=cfg.sliding_window,
+                          q_block=opts.q_block)
+        o = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+        x = x + o
+        x = constrain(x, "batch", "seq", "act_embed")
 
-    h = rms_norm(x, p["ln2"], cfg.norm_eps)
     aux = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32))
-    if is_moe:
-        hf = h.reshape(B * S, d)
-        out, a, z = moe.moe_ffn(hf, p["router"], p["w_gate"], p["w_up"],
-                                p["w_down"], top_k=cfg.top_k,
-                                cf=cfg.capacity_factor)
-        x = x + out.reshape(B, S, d)
-        aux = (a, z)
-    else:
-        x = x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
+    with jax.named_scope("mlp"):
+        h = rms_norm(x, p["ln2"], cfg.norm_eps)
+        if is_moe:
+            hf = h.reshape(B * S, d)
+            out, a, z = moe.moe_ffn(hf, p["router"], p["w_gate"],
+                                    p["w_up"], p["w_down"], top_k=cfg.top_k,
+                                    cf=cfg.capacity_factor)
+            x = x + out.reshape(B, S, d)
+            aux = (a, z)
+        else:
+            x = x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"])
     x = constrain(x, "batch", "seq", "act_embed")
     return x, new_cache, aux
 
@@ -490,10 +496,11 @@ def _inputs_to_x(cfg, params, tokens, inputs_embeds, prefix_embeds):
 
 
 def _logits(cfg, params, x):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tie_embeddings and cfg.embed_inputs:
-        return x @ params["embed"].T
-    return x @ params["lm_head"]
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        if cfg.tie_embeddings and cfg.embed_inputs:
+            return x @ params["embed"].T
+        return x @ params["lm_head"]
 
 
 def apply(params, cfg, *, tokens=None, inputs_embeds=None, prefix_embeds=None,

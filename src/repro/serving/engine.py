@@ -69,8 +69,9 @@ from repro.models import transformer as T
 from repro.serving.paged import paged_compatible
 from repro.serving.pool import DenseCachePool, PagedCachePool
 from repro.serving.scheduler import ContinuousScheduler, SchedulerConfig
-from repro.serving.stats import (EngineStats, expected_time_per_token,
-                                 slo_headroom, slo_summary)
+from repro.serving.stats import (EngineStats, annotate,
+                                 expected_time_per_token, slo_headroom,
+                                 slo_summary, span)
 
 
 def _bucket(n: int, align: int = 16) -> int:
@@ -372,16 +373,16 @@ class SpinEngine:
         self.rng = jax.random.PRNGKey(ecfg.seed)
         # metrics
         self.sim_time = 0.0
-        self.wall_time = 0.0
+        self.slots = 0                     # simulated slots so far
         self.accepted_tokens = 0
         self.total_drafted = 0
         self.verify_tokens_total = 0       # LLM verify query tokens issued
         self.tree_forks = 0                # CoW row forks (tree mode)
         self.tree_adoptions = 0            # slots won by a non-main branch
         self.prefill_tokens_total = 0
-        self.slot_log: List[dict] = []
         self.straggler_redispatches = 0
-        self._accept_by_req: Dict[int, List[float]] = {}
+        # per request: (sum, count) of its per-slot acceptance rates
+        self._accept_by_req: Dict[int, tuple] = {}
         # prefill work issued since the last slot simulation (monolithic
         # admissions and chunk appends); consumed into the next slot's
         # makespan so prompt ingestion is paid for on the sim clock
@@ -502,16 +503,17 @@ class SpinEngine:
         prefill chunks are appended.  ``grant_prefill`` is True only for
         the start-of-step pass so the chunk budget is spent once per slot
         (end-of-step recycling and ``add_requests`` only move rows)."""
-        dec = self.scheduler.plan(self.sim_time,
-                                  grant_prefill=grant_prefill)
-        for r in dec.preempt:
-            self._preempt(r)
-        for r in dec.admit:
-            if r.first_token_time is None:
-                self._unstamped.add(r.rid)
-            self._begin_admit(r)
-        for r, n in dec.prefill:
-            self._prefill_chunk(r, n)
+        with span("spin.schedule"):
+            dec = self.scheduler.plan(self.sim_time,
+                                      grant_prefill=grant_prefill)
+            for r in dec.preempt:
+                self._preempt(r)
+            for r in dec.admit:
+                if r.first_token_time is None:
+                    self._unstamped.add(r.rid)
+                self._begin_admit(r)
+            for r, n in dec.prefill:
+                self._prefill_chunk(r, n)
 
     @staticmethod
     def _context_tokens(r: Request) -> np.ndarray:
@@ -522,32 +524,45 @@ class SpinEngine:
                                np.asarray(r.emitted[:-1] if r.emitted
                                           else [], np.int64)])
 
+    @staticmethod
+    def _admit_meta(r: Request):
+        return lambda: {"rid": r.rid,
+                        "context": r.prompt_len + max(0, len(r.emitted or [])
+                                                      - 1)}
+
     def _begin_admit(self, r: Request):
         """Grant the request a pool row.  Monolithic mode prefills the
         whole context here (fresh prompt, or prompt + committed tokens
         after preemption — greedy continuation stays bit-identical to an
         uninterrupted run).  Chunked mode only takes the row; context
-        arrives through :meth:`_prefill_chunk` grants."""
-        self.requests[r.rid] = r
-        if self.chunked:
-            r.prefill_pos = 0
-            self.llm_pool.insert_empty(r.rid)
+        arrives through :meth:`_prefill_chunk` grants.  The first
+        admission stamps ``host_admitted``."""
+        with span("spin.admit", self._admit_meta(r)):
+            if r.host_admitted is None:
+                r.host_admitted = time.perf_counter()
+            self.requests[r.rid] = r
+            if self.chunked:
+                r.prefill_pos = 0
+                self.llm_pool.insert_empty(r.rid)
+                self.scheduler.mark_admitted(r, self.sim_time)
+                return
+            tokens = self._context_tokens(r)
+            L = len(tokens)
+            row = np.zeros((1, _bucket(L)), np.int32)
+            row[0, :L] = tokens
+            lengths = jnp.asarray([L], jnp.int32)
+            # paged: prefill a cache of just the prompt's blocks —
+            # admission cost is O(prompt blocks), independent of pool
+            # capacity/max_len
+            plen = (self.llm_pool.prefill_len(row.shape[1]) if self.paged
+                    else self.max_len)
+            with span("spin.prefill", lambda: {"rid": r.rid, "tokens": L}):
+                logits, cache = self.llm.prefill(jnp.asarray(row), lengths,
+                                                 plen)
+                last = self._first_token(r, logits, L - 1)
+            self.llm_pool.insert(r.rid, cache, L, last)
+            self._account_prefill(0, L)
             self.scheduler.mark_admitted(r, self.sim_time)
-            return
-        tokens = self._context_tokens(r)
-        L = len(tokens)
-        row = np.zeros((1, _bucket(L)), np.int32)
-        row[0, :L] = tokens
-        lengths = jnp.asarray([L], jnp.int32)
-        # paged: prefill a cache of just the prompt's blocks — admission
-        # cost is O(prompt blocks), independent of pool capacity/max_len
-        plen = (self.llm_pool.prefill_len(row.shape[1]) if self.paged
-                else self.max_len)
-        logits, cache = self.llm.prefill(jnp.asarray(row), lengths, plen)
-        last = self._first_token(r, logits, L - 1)
-        self.llm_pool.insert(r.rid, cache, L, last)
-        self._account_prefill(0, L)
-        self.scheduler.mark_admitted(r, self.sim_time)
 
     def _first_token(self, r: Request, logits, idx: int) -> int:
         """The token that follows the ingested context — the emitted tail
@@ -558,6 +573,8 @@ class SpinEngine:
             return int(r.emitted[-1])
         last = int(jnp.argmax(logits[0, idx, :self.llm.cfg.vocab_size]))
         r.emitted = [last]
+        if r.host_first_token is None:
+            r.host_first_token = time.perf_counter()
         return last
 
     def _account_prefill(self, pos: int, n: int):
@@ -581,32 +598,35 @@ class SpinEngine:
         n = min(n, L - pos)
         if n <= 0:
             return
-        Tb = _bucket(n, 8)
-        toks = np.zeros((1, Tb), np.int32)
-        toks[0, :n] = ctx[pos:pos + n]
-        segs = np.full((1, Tb), -1, np.int32)
-        segs[0, :n] = 0
-        lengths = jnp.asarray([pos], jnp.int32)
-        if self.paged:
-            self.llm_pool.ensure(rid, pos + n)
-            bt = self.llm_pool.row_table(rid)
-            logits, cache = self.llm.append_paged(
-                self.llm_pool.cache, jnp.asarray(toks), lengths,
-                jnp.asarray(segs), bt, self.fused_cfg)
-            self.llm_pool.cache = cache
-        else:
-            one = self.llm_pool.row_cache(rid)
-            logits, one = self.llm.append(one, jnp.asarray(toks), lengths,
-                                          jnp.asarray(segs))
-            self.llm_pool.write_row(rid, one)
-        r.prefill_pos = pos + n
-        row = self.llm_pool.row_of[rid]
-        self.llm_pool.lengths[row] = r.prefill_pos
-        self._account_prefill(pos, n)
-        if r.prefill_pos >= L:
-            self.llm_pool.last_token[row] = self._first_token(r, logits,
-                                                              n - 1)
-            self.scheduler.mark_prefill_done(r)
+        with span("spin.admit", self._admit_meta(r)):
+            Tb = _bucket(n, 8)
+            toks = np.zeros((1, Tb), np.int32)
+            toks[0, :n] = ctx[pos:pos + n]
+            segs = np.full((1, Tb), -1, np.int32)
+            segs[0, :n] = 0
+            lengths = jnp.asarray([pos], jnp.int32)
+            with span("spin.prefill", lambda: {"rid": rid, "tokens": n}):
+                if self.paged:
+                    self.llm_pool.ensure(rid, pos + n)
+                    bt = self.llm_pool.row_table(rid)
+                    logits, cache = self.llm.append_paged(
+                        self.llm_pool.cache, jnp.asarray(toks), lengths,
+                        jnp.asarray(segs), bt, self.fused_cfg)
+                    self.llm_pool.cache = cache
+                else:
+                    one = self.llm_pool.row_cache(rid)
+                    logits, one = self.llm.append(
+                        one, jnp.asarray(toks), lengths, jnp.asarray(segs))
+                    self.llm_pool.write_row(rid, one)
+                first = (self._first_token(r, logits, n - 1)
+                         if pos + n >= L else None)
+            r.prefill_pos = pos + n
+            row = self.llm_pool.row_of[rid]
+            self.llm_pool.lengths[row] = r.prefill_pos
+            self._account_prefill(pos, n)
+            if first is not None:
+                self.llm_pool.last_token[row] = first
+                self.scheduler.mark_prefill_done(r)
 
     def _preempt(self, r: Request):
         """Release the request's row and draft-pool slot; generated tokens
@@ -686,8 +706,30 @@ class SpinEngine:
                 self._stamp_tokens(r)
                 self._unstamped.discard(rid)
 
+    def kv_cells(self):
+        """(used, held, alloc) KV cells summed over the target pool and
+        every drafter pool (see ``PagedCachePool.cells``)."""
+        used = held = alloc = 0
+        for pool in [self.llm_pool, *self.ssm_pools]:
+            u, h, a = pool.cells()
+            used, held, alloc = used + u, held + h, alloc + a
+        return used, held, alloc
+
+    def _step_meta(self, rec: dict) -> dict:
+        used, held, alloc = self.kv_cells()
+        return {"rows": rec.get("active", 0),
+                "waiting": len(self.scheduler.waiting),
+                "kv_used": used, "kv_held": held, "kv_alloc": alloc}
+
     def step(self) -> dict:
-        t_wall = time.perf_counter()
+        """One slot (module docstring), inside the ``spin.step`` span;
+        ``docs/SERVING.md`` lists the span tree."""
+        with span("spin.step") as s:
+            rec = self._step()
+            annotate(s, lambda: self._step_meta(rec))
+        return rec
+
+    def _step(self) -> dict:
         self._schedule(grant_prefill=True)
         active = self._active()
         if not active:
@@ -702,36 +744,70 @@ class SpinEngine:
             if self._prefill_tokens_pending > 0:
                 # prefill-only slot: every row is still ingesting context;
                 # the clock advances by the chunk work just issued
-                pre_t, pre_n = self._consume_prefill()
-                self.sim_time += pre_t
-                self._stamp_first_tokens()
-                self.wall_time += time.perf_counter() - t_wall
-                rec = {"tokens": 0, "sim_time": pre_t, "llm_idle": 0.0,
-                       "micro_batches": [], "active": 0,
-                       "running": len(self.scheduler.running),
-                       "queued": len(self.scheduler.waiting),
-                       "prefill_tokens": pre_n}
-                self.slot_log.append(rec)
-                return rec
+                with span("spin.commit"):
+                    pre_t, pre_n = self._consume_prefill()
+                    self.sim_time += pre_t
+                    self._stamp_first_tokens()
+                self.slots += 1
+                return {"tokens": 0, "sim_time": pre_t, "llm_idle": 0.0,
+                        "micro_batches": [], "active": 0,
+                        "running": len(self.scheduler.running),
+                        "queued": len(self.scheduler.waiting),
+                        "prefill_tokens": pre_n}
             return {"done": True}
         ids = [r.rid for r in active]
-        assign = self.selector.assign(ids)
+        with span("spin.assign") as s:
+            assign = self.selector.assign(ids)
+            # apply switches / placements
+            switches = 0
+            for rid, j in assign.items():
+                if j in self.failed_ssms:
+                    j = min(set(range(len(self.ssms))) - self.failed_ssms)
+                    assign[rid] = j
+                prev = self.assignment.get(rid)
+                if prev == j and self.ssm_pools[j].has(rid):
+                    continue
+                if prev is not None and prev != j and \
+                        self.ssm_pools[prev].has(rid):
+                    self.ssm_pools[prev].evict(rid)
+                if not self.ssm_pools[j].has(rid):
+                    self._place_on_ssm(rid, j, assign)
+                    switches += 1
+                self.assignment[rid] = j
+            annotate(s, lambda: {"switches": switches})
+        with span("spin.draft"):
+            drafts, depths, per_ssm = self._draft(active, ids, assign)
+        self.total_drafted += sum(depths.values())
+        self.verify_tokens_total += sum(
+            depths[rid] + self._beff(depths[rid]) for rid in ids)
 
-        # apply switches / placements
-        for rid, j in assign.items():
-            if j in self.failed_ssms:
-                j = min(set(range(len(self.ssms))) - self.failed_ssms)
-                assign[rid] = j
-            prev = self.assignment.get(rid)
-            if prev == j and self.ssm_pools[j].has(rid):
-                continue
-            if prev is not None and prev != j and \
-                    self.ssm_pools[prev].has(rid):
-                self.ssm_pools[prev].evict(rid)
-            if not self.ssm_pools[j].has(rid):
-                self._place_on_ssm(rid, j, assign)
-            self.assignment[rid] = j
+        # verification (functional, full batch; per-row depth masking)
+        n_acc, out, out_len = self._verify(ids, drafts, depths)
 
+        with span("spin.commit"):
+            slot_tokens, mb, slot, pre_n = self._commit(
+                ids, assign, depths, n_acc, out, out_len, per_ssm)
+        self.slots += 1
+
+        # fast-switching prediction for next slot (§IV-C)
+        with span("spin.precompute"):
+            self._precompute_switches(ids)
+        # recycle rows freed by finished requests within the SAME step:
+        # queued arrivals are admitted into them before the slot returns
+        self._schedule()
+
+        return {"tokens": slot_tokens, "sim_time": slot.makespan,
+                "llm_idle": slot.llm_idle_frac, "micro_batches": mb,
+                "active": len(ids),
+                "running": len(self.scheduler.running),
+                "queued": len(self.scheduler.waiting),
+                "prefill_tokens": pre_n}
+
+    def _draft(self, active, ids, assign):
+        """Grant each request its depth and draft on every SSM pool:
+        returns (drafts, depths, per-SSM costs), the costs being the
+        per-SSM batch, mean depth and mean extra verify tokens the slot
+        simulation charges."""
         # per-request speculation depths for this slot (goodput-aware
         # argmax on the selector's acceptance estimates; "fixed" policy:
         # the uniform ecfg.gamma).  The cap charges the prompt-chunk
@@ -783,24 +859,27 @@ class SpinEngine:
             per_ssm_vextra.append(float(np.mean(
                 [self._beff(depths[r]) - 1 for r in rids])))
             width = max(depths[r] for r in rids)
+            with span("spin.draft", lambda: {"ssm": j, "width": width}):
+                if self.tree:
+                    cand, branch_map = self._draft_pool_tree(
+                        j, width, depths, rids)
+                else:
+                    cand = self._draft_pool(j, width, depths)
             if self.tree:
-                cand, branch_map = self._draft_pool_tree(
-                    j, width, depths, rids)
                 for rid in rids:
                     drafts[rid] = [cand[row, :kk]
                                    for row, kk in branch_map[rid]]
             else:
-                cand = self._draft_pool(j, width, depths)
                 rows = pool.rows(rids)
                 for rid, row in zip(rids, rows):
                     drafts[rid] = cand[row, :depths[rid]]
-        self.total_drafted += sum(depths.values())
-        self.verify_tokens_total += sum(
-            depths[rid] + self._beff(depths[rid]) for rid in ids)
+        return drafts, depths, (per_ssm_batch, per_ssm_depth, per_ssm_vextra)
 
-        # verification (functional, full batch; per-row depth masking)
-        n_acc, out, out_len = self._verify(ids, drafts, depths)
-
+    def _commit(self, ids, assign, depths, n_acc, out, out_len, per_ssm):
+        """Simulate the slot on the sim clock and commit its tokens:
+        returns (tokens committed, micro-batches, the slot's
+        ``SimResult``, prefill tokens it carried)."""
+        per_ssm_batch, per_ssm_depth, per_ssm_vextra = per_ssm
         # simulated slot timeline (pipeline §V-B); verification cost sees
         # the padded vs decomposed-packed KV grid size (§V-A), ragged per
         # SSM under continuous batching — and ragged draft depths under
@@ -845,27 +924,13 @@ class SpinEngine:
             rate = float(n_acc[i]) / tested
             if observe_accept is not None:
                 observe_accept(rid, assign[rid], rate)
-            self._accept_by_req.setdefault(rid, []).append(rate)
+            total, n = self._accept_by_req.get(rid, (0.0, 0))
+            self._accept_by_req[rid] = (total + rate, n + 1)
             if len(r.emitted) - 1 >= r.max_new:
                 self._finish(r)
         self.accepted_tokens += slot_tokens
         self._stamp_first_tokens()
-        self.wall_time += time.perf_counter() - t_wall
-
-        # fast-switching prediction for next slot (§IV-C)
-        self._precompute_switches(ids)
-        # recycle rows freed by finished requests within the SAME step:
-        # queued arrivals are admitted into them before the slot returns
-        self._schedule()
-
-        rec = {"tokens": slot_tokens, "sim_time": slot.makespan,
-               "llm_idle": slot.llm_idle_frac, "micro_batches": mb,
-               "active": len(ids),
-               "running": len(self.scheduler.running),
-               "queued": len(self.scheduler.waiting),
-               "prefill_tokens": pre_n}
-        self.slot_log.append(rec)
-        return rec
+        return slot_tokens, mb, slot, pre_n
 
     # ---------------------------------------------------------- internals --
     def _switch_width(self, j: int, length: int) -> int:
@@ -1074,191 +1139,196 @@ class SpinEngine:
         accept beyond its grant, and whose speculative KV writes land in
         the rollback scrub window like any rejected draft."""
         W = max(depths[rid] for rid in ids)
-        N = self.llm_pool.capacity
-        # tree mode: fork a CoW row per extra branch BEFORE capturing the
-        # pool arrays — each branch verifies its own root copy + chain
-        # through its own (prefix-shared) block table
-        fork_rows: Dict[int, list] = {}
-        tree_rows = None
-        if self.tree:
-            tree_rows = {}
-            for rid in ids:
-                bd = D.split_tree_depths(depths[rid], self.branches)
-                mrow = self.llm_pool.row_of[rid]
-                L = int(self.llm_pool.lengths[mrow])
-                lst = []
-                for jj in range(1, len(bd)):
-                    brid = self._brid(rid, jj)
-                    brow = self.llm_pool.fork(rid, brid)
-                    lst.append((jj, brid, brow))
-                    self.tree_forks += 1
-                if lst:
-                    # un-share the speculation window: every branch (and
-                    # the main row, last so it keeps the originals) writes
-                    # through private block copies
-                    for jj, brid, brow in lst:
-                        self.llm_pool.cow_prepare(brid, L, L + W + 2)
-                    self.llm_pool.cow_prepare(rid, L, L + W + 2)
-                fork_rows[rid] = lst
-                tree_rows[mrow] = (mrow, 0, bd[0])
-                off = bd[0] + 1
-                for jj, brid, brow in lst:
-                    tree_rows[brow] = (mrow, off, bd[jj])
-                    off += bd[jj] + 1
-        cand = np.zeros((N, W), np.int32)
-        k_row = np.zeros(N, np.int64)
-        lengths = jnp.asarray(self.llm_pool.lengths, jnp.int32)
-        last = jnp.asarray(self.llm_pool.last_token, jnp.int32)[:, None]
-        rows = self.llm_pool.rows(ids)
-        for rid, row in zip(ids, rows):
+        with span("spin.verify", lambda: {"width": W}):
+            N = self.llm_pool.capacity
+            # tree mode: fork a CoW row per extra branch BEFORE capturing the
+            # pool arrays — each branch verifies its own root copy + chain
+            # through its own (prefix-shared) block table
+            fork_rows: Dict[int, list] = {}
+            tree_rows = None
             if self.tree:
-                bd = D.split_tree_depths(depths[rid], self.branches)
-                chains = drafts.get(
-                    rid, [np.zeros(kk, np.int32) for kk in bd])
-                cand[row, :len(chains[0])] = chains[0]
-                k_row[row] = bd[0]
-                for (jj, brid, brow) in fork_rows[rid]:
-                    cand[brow, :len(chains[jj])] = chains[jj]
-                    k_row[brow] = bd[jj]
+                tree_rows = {}
+                for rid in ids:
+                    bd = D.split_tree_depths(depths[rid], self.branches)
+                    mrow = self.llm_pool.row_of[rid]
+                    L = int(self.llm_pool.lengths[mrow])
+                    lst = []
+                    for jj in range(1, len(bd)):
+                        brid = self._brid(rid, jj)
+                        brow = self.llm_pool.fork(rid, brid)
+                        lst.append((jj, brid, brow))
+                        self.tree_forks += 1
+                    if lst:
+                        # un-share the speculation window: every branch (and
+                        # the main row, last so it keeps the originals) writes
+                        # through private block copies
+                        for jj, brid, brow in lst:
+                            self.llm_pool.cow_prepare(brid, L, L + W + 2)
+                        self.llm_pool.cow_prepare(rid, L, L + W + 2)
+                    fork_rows[rid] = lst
+                    tree_rows[mrow] = (mrow, 0, bd[0])
+                    off = bd[0] + 1
+                    for jj, brid, brow in lst:
+                        tree_rows[brow] = (mrow, off, bd[jj])
+                        off += bd[jj] + 1
+            cand = np.zeros((N, W), np.int32)
+            k_row = np.zeros(N, np.int64)
+            lengths = jnp.asarray(self.llm_pool.lengths, jnp.int32)
+            last = jnp.asarray(self.llm_pool.last_token, jnp.int32)[:, None]
+            rows = self.llm_pool.rows(ids)
+            for rid, row in zip(ids, rows):
+                if self.tree:
+                    bd = D.split_tree_depths(depths[rid], self.branches)
+                    chains = drafts.get(
+                        rid, [np.zeros(kk, np.int32) for kk in bd])
+                    cand[row, :len(chains[0])] = chains[0]
+                    k_row[row] = bd[0]
+                    for (jj, brid, brow) in fork_rows[rid]:
+                        cand[brow, :len(chains[jj])] = chains[jj]
+                        k_row[brow] = bd[jj]
+                else:
+                    d = drafts.get(rid, np.zeros(depths[rid], np.int32))
+                    cand[row, :len(d)] = d
+                    k_row[row] = depths[rid]
+            cand = jnp.asarray(cand)
+
+            if self.ecfg.use_packed_verify:
+                logits = self._verify_packed(cand, lengths, last, W,
+                                             tree_rows=tree_rows)
             else:
-                d = drafts.get(rid, np.zeros(depths[rid], np.int32))
-                cand[row, :len(d)] = d
-                k_row[row] = depths[rid]
-        cand = jnp.asarray(cand)
+                inp = jnp.concatenate([last, cand], axis=1)
+                if self.paged:
+                    bt, _ = self.llm_pool.block_table_array()
+                    logits, cache = self.llm.decode_paged(
+                        self.llm_pool.cache, inp, lengths, bt,
+                        self.fused_cfg)
+                else:
+                    logits, cache = self.llm.decode(self.llm_pool.cache, inp,
+                                                    lengths)
+                self.llm_pool.cache = cache
+            V = self.llm.cfg.vocab_size
+            greedy = jnp.argmax(logits.astype(jnp.float32)[..., :V],
+                                axis=-1).astype(jnp.int32)
+            # per-row depth mask: positions at or beyond a row's grant can
+            # never match (they hold padding, not drafts)
+            in_depth = (jnp.arange(W)[None]
+                        < jnp.asarray(k_row, jnp.int32)[:, None])
+            match = (greedy[:, :W] == cand) & in_depth
+            n_acc_all = jnp.sum(jnp.cumprod(match.astype(jnp.int32), 1), 1)
+            idx = jnp.arange(W + 1)[None]
+            out_all = jnp.where(idx < n_acc_all[:, None],
+                                jnp.pad(cand, ((0, 0), (0, 1))), 0)
+            bonus = jnp.take_along_axis(greedy, n_acc_all[:, None], axis=1)
+            out_all = out_all.at[jnp.arange(N), n_acc_all].set(bonus[:, 0])
 
-        if self.ecfg.use_packed_verify:
-            logits = self._verify_packed(cand, lengths, last, W,
-                                         tree_rows=tree_rows)
-        else:
-            inp = jnp.concatenate([last, cand], axis=1)
+            # tree: adopt the winning branch per request — the row with the
+            # longest accepted root-to-leaf path keeps the request id (its CoW
+            # copies become canonical); losers are evicted in O(branches),
+            # dropping refs so shared prefix blocks survive via the winner.
+            # Under greedy verification at most one branch accepts >= 1 token
+            # (branches differ at their first draft and only the one matching
+            # the LLM argmax can accept), so ties land on branch 0 and the
+            # bonus token is the LLM's own pick — lossless at any shape.
+            winner_row = {rid: row for rid, row in zip(ids, rows)}
+            if self.tree:
+                n_acc_np = np.asarray(n_acc_all)
+                for rid in ids:
+                    best_j, best_row = 0, winner_row[rid]
+                    for (jj, brid, brow) in fork_rows[rid]:
+                        if int(n_acc_np[brow]) > int(n_acc_np[best_row]):
+                            best_j, best_row = jj, brow
+                    if best_j != 0:
+                        self.llm_pool.evict(rid)
+                        self.llm_pool.rename(self._brid(rid, best_j), rid)
+                        self.tree_adoptions += 1
+                    for (jj, brid, brow) in fork_rows[rid]:
+                        if jj != best_j:
+                            self.llm_pool.evict(brid)
+                    winner_row[rid] = best_row
+
+        with span("spin.rollback"):
+            # rollback: keep accepted prefix only (paged: trim the tail block
+            # in place — a W-wide seg scatter through the block table)
             if self.paged:
-                bt, _ = self.llm_pool.block_table_array()
-                logits, cache = self.llm.decode_paged(
-                    self.llm_pool.cache, inp, lengths, bt,
-                    self.fused_cfg)
-            else:
-                logits, cache = self.llm.decode(self.llm_pool.cache, inp,
-                                                lengths)
-            self.llm_pool.cache = cache
-        V = self.llm.cfg.vocab_size
-        greedy = jnp.argmax(logits.astype(jnp.float32)[..., :V],
-                            axis=-1).astype(jnp.int32)
-        # per-row depth mask: positions at or beyond a row's grant can
-        # never match (they hold padding, not drafts)
-        in_depth = jnp.arange(W)[None] < jnp.asarray(k_row, jnp.int32)[:, None]
-        match = (greedy[:, :W] == cand) & in_depth
-        n_acc_all = jnp.sum(jnp.cumprod(match.astype(jnp.int32), 1), 1)
-        idx = jnp.arange(W + 1)[None]
-        out_all = jnp.where(idx < n_acc_all[:, None],
-                            jnp.pad(cand, ((0, 0), (0, 1))), 0)
-        bonus = jnp.take_along_axis(greedy, n_acc_all[:, None], axis=1)
-        out_all = out_all.at[jnp.arange(N), n_acc_all].set(bonus[:, 0])
-
-        # tree: adopt the winning branch per request — the row with the
-        # longest accepted root-to-leaf path keeps the request id (its CoW
-        # copies become canonical); losers are evicted in O(branches),
-        # dropping refs so shared prefix blocks survive via the winner.
-        # Under greedy verification at most one branch accepts >= 1 token
-        # (branches differ at their first draft and only the one matching
-        # the LLM argmax can accept), so ties land on branch 0 and the
-        # bonus token is the LLM's own pick — lossless at any shape.
-        winner_row = {rid: row for rid, row in zip(ids, rows)}
-        if self.tree:
-            n_acc_np = np.asarray(n_acc_all)
-            for rid in ids:
-                best_j, best_row = 0, winner_row[rid]
-                for (jj, brid, brow) in fork_rows[rid]:
-                    if int(n_acc_np[brow]) > int(n_acc_np[best_row]):
-                        best_j, best_row = jj, brow
-                if best_j != 0:
-                    self.llm_pool.evict(rid)
-                    self.llm_pool.rename(self._brid(rid, best_j), rid)
-                    self.tree_adoptions += 1
-                for (jj, brid, brow) in fork_rows[rid]:
-                    if jj != best_j:
-                        self.llm_pool.evict(brid)
-                winner_row[rid] = best_row
-
-        # rollback: keep accepted prefix only (paged: trim the tail block
-        # in place — a W-wide seg scatter through the block table)
-        if self.paged:
-            self.llm_pool.invalidate_span(lengths + 1 + n_acc_all,
-                                          lengths + W + 1, W=W)
-        else:
-            self.llm_pool.cache = sd.invalidate_slots_jit(
-                self.llm_pool.cache, lengths + 1 + n_acc_all,
-                lengths + W + 1)
-            self.llm_pool.invalidate_rows(
-                [row for row in range(N)
-                 if row not in self.llm_pool.row_of.values()])
-        # prefilling rows are live pool rows but take no part in this
-        # verify: the full-pool forward still wrote speculative KV at
-        # their positions [len, len+W+1) — scrub all of it, or a later
-        # chunk landing below those positions would leave stale
-        # attendable garbage beyond the context
-        pre_rows = [self.llm_pool.row_of[rid]
-                    for rid in self.scheduler.prefilling
-                    if rid in self.llm_pool.row_of]
-        if pre_rows:
-            lo = np.zeros(N, np.int64)
-            hi = np.zeros(N, np.int64)
-            lens_now = np.asarray(self.llm_pool.lengths, np.int64)
-            for row in pre_rows:
-                lo[row] = lens_now[row]
-                hi[row] = lens_now[row] + W + 1
-            if self.paged:
-                self.llm_pool.invalidate_span(
-                    jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
-                    W=W + 1)
+                self.llm_pool.invalidate_span(lengths + 1 + n_acc_all,
+                                              lengths + W + 1, W=W)
             else:
                 self.llm_pool.cache = sd.invalidate_slots_jit(
-                    self.llm_pool.cache, jnp.asarray(lo, jnp.int32),
-                    jnp.asarray(hi, jnp.int32))
+                    self.llm_pool.cache, lengths + 1 + n_acc_all,
+                    lengths + W + 1)
+                self.llm_pool.invalidate_rows(
+                    [row for row in range(N)
+                     if row not in self.llm_pool.row_of.values()])
+            # prefilling rows are live pool rows but take no part in this
+            # verify: the full-pool forward still wrote speculative KV at
+            # their positions [len, len+W+1) — scrub all of it, or a later
+            # chunk landing below those positions would leave stale
+            # attendable garbage beyond the context
+            pre_rows = [self.llm_pool.row_of[rid]
+                        for rid in self.scheduler.prefilling
+                        if rid in self.llm_pool.row_of]
+            if pre_rows:
+                lo = np.zeros(N, np.int64)
+                hi = np.zeros(N, np.int64)
+                lens_now = np.asarray(self.llm_pool.lengths, np.int64)
+                for row in pre_rows:
+                    lo[row] = lens_now[row]
+                    hi[row] = lens_now[row] + W + 1
+                if self.paged:
+                    self.llm_pool.invalidate_span(
+                        jnp.asarray(lo, jnp.int32), jnp.asarray(hi, jnp.int32),
+                        W=W + 1)
+                else:
+                    self.llm_pool.cache = sd.invalidate_slots_jit(
+                        self.llm_pool.cache, jnp.asarray(lo, jnp.int32),
+                        jnp.asarray(hi, jnp.int32))
 
-        # per-SSM catch-up (fill the c_k hole) + rollback on draft pools
-        for j, pool in enumerate(self.ssm_pools):
-            if not pool.row_of:
-                continue
-            pl = jnp.asarray(pool.lengths, jnp.int32)
-            outs_j = np.zeros((pool.capacity, W + 1), np.int32)
-            nacc_j = np.zeros(pool.capacity, np.int64)
-            for rid, row in pool.row_of.items():
-                lrow = self.llm_pool.row_of.get(rid)
-                if lrow is None:
+        with span("spin.catchup"):
+            # per-SSM catch-up (fill the c_k hole) + rollback on draft pools
+            for j, pool in enumerate(self.ssm_pools):
+                if not pool.row_of:
                     continue
-                outs_j[row] = np.asarray(out_all[lrow])
-                nacc_j[row] = int(n_acc_all[lrow])
-            if self.paged:
-                bt, _ = pool.block_table_array()
-                _, pool.cache = self.ssms[j].decode_paged(
-                    pool.cache, jnp.asarray(outs_j), pl + 1, bt,
-                    self.fused_cfg)
-                pool.invalidate_span(
-                    pl + 2 + jnp.asarray(nacc_j, jnp.int32),
-                    pl + W + 3, W=W + 1)
-            else:
-                _, pool.cache = self.ssms[j].decode(
-                    pool.cache, jnp.asarray(outs_j), pl + 1)
-                pool.cache = sd.invalidate_slots_jit(
-                    pool.cache, pl + 2 + jnp.asarray(nacc_j, jnp.int32),
-                    pl + W + 3)
+                pl = jnp.asarray(pool.lengths, jnp.int32)
+                outs_j = np.zeros((pool.capacity, W + 1), np.int32)
+                nacc_j = np.zeros(pool.capacity, np.int64)
+                for rid, row in pool.row_of.items():
+                    lrow = self.llm_pool.row_of.get(rid)
+                    if lrow is None:
+                        continue
+                    outs_j[row] = np.asarray(out_all[lrow])
+                    nacc_j[row] = int(n_acc_all[lrow])
+                if self.paged:
+                    bt, _ = pool.block_table_array()
+                    _, pool.cache = self.ssms[j].decode_paged(
+                        pool.cache, jnp.asarray(outs_j), pl + 1, bt,
+                        self.fused_cfg)
+                    pool.invalidate_span(
+                        pl + 2 + jnp.asarray(nacc_j, jnp.int32),
+                        pl + W + 3, W=W + 1)
+                else:
+                    _, pool.cache = self.ssms[j].decode(
+                        pool.cache, jnp.asarray(outs_j), pl + 1)
+                    pool.cache = sd.invalidate_slots_jit(
+                        pool.cache, pl + 2 + jnp.asarray(nacc_j, jnp.int32),
+                        pl + W + 3)
 
-        # update lengths / last tokens on pools
-        n_acc = np.zeros(len(ids), np.int64)
-        out = np.zeros((len(ids), W + 1), np.int64)
-        out_len = np.zeros(len(ids), np.int64)
-        for i, rid in enumerate(ids):
-            row = winner_row[rid]
-            n_acc[i] = int(n_acc_all[row])
-            out[i] = np.asarray(out_all[row])
-            out_len[i] = n_acc[i] + 1
-            self.llm_pool.lengths[row] += out_len[i]
-            self.llm_pool.last_token[row] = out[i, n_acc[i]]
-            j = self.assignment[rid]
-            srow = self.ssm_pools[j].row_of[rid]
-            self.ssm_pools[j].lengths[srow] += out_len[i]
-            self.ssm_pools[j].last_token[srow] = out[i, n_acc[i]]
-        return n_acc, out, out_len
+        with span("spin.commit"):
+            # update lengths / last tokens on pools
+            n_acc = np.zeros(len(ids), np.int64)
+            out = np.zeros((len(ids), W + 1), np.int64)
+            out_len = np.zeros(len(ids), np.int64)
+            for i, rid in enumerate(ids):
+                row = winner_row[rid]
+                n_acc[i] = int(n_acc_all[row])
+                out[i] = np.asarray(out_all[row])
+                out_len[i] = n_acc[i] + 1
+                self.llm_pool.lengths[row] += out_len[i]
+                self.llm_pool.last_token[row] = out[i, n_acc[i]]
+                j = self.assignment[rid]
+                srow = self.ssm_pools[j].row_of[rid]
+                self.ssm_pools[j].lengths[srow] += out_len[i]
+                self.ssm_pools[j].last_token[srow] = out[i, n_acc[i]]
+            return n_acc, out, out_len
 
     def _verify_packed(self, cand, lengths, last, W: int, tree_rows=None):
         """Packed verification via request decomposition (§V-A) at the
@@ -1381,7 +1451,7 @@ class SpinEngine:
     def _with_straggler_mitigation(self, cost, per_ssm_batch):
         """Inject random stragglers; mitigation re-dispatches the straggling
         micro-batch to the fastest live SSM (bounded delay)."""
-        jitter = np.random.default_rng(len(self.slot_log)).exponential(
+        jitter = np.random.default_rng(self.slots).exponential(
             1.0, len(self.ssms))
         slow = jitter > self.ecfg.straggler_factor
         if not slow.any():
@@ -1434,7 +1504,6 @@ class SpinEngine:
             "accepted_tokens": self.accepted_tokens,
             "prefill_tokens": self.prefill_tokens_total,
             "sim_time": self.sim_time,
-            "wall_time": self.wall_time,
             "goodput_sim": self.accepted_tokens / max(self.sim_time, 1e-9),
             "ttft_p50": float(np.percentile(ttft, 50)) if ttft else 0.0,
             "ttft_p95": float(np.percentile(ttft, 95)) if ttft else 0.0,
@@ -1445,6 +1514,6 @@ class SpinEngine:
             "p95_latency": float(np.percentile(lat, 95)) if lat else 0.0,
             "straggler_redispatches": self.straggler_redispatches,
             "mean_accept": float(np.mean([
-                np.mean(v) for v in self._accept_by_req.values()]))
+                total / n for total, n in self._accept_by_req.values()]))
             if self._accept_by_req else 0.0,
         }

@@ -53,6 +53,7 @@ from __future__ import annotations
 
 from typing import Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import quant
@@ -93,25 +94,26 @@ def _write_kv(kv_cache, widx_flat, k_new, v_new, positions, segments,
     kernels/quant.py) quantize each new token's K/V per (slot, head) on
     the way in and scatter the scales into the same flat slots, so the
     write stays one pass and no dequantized pool copy ever exists."""
-    Kh, hd = kv_cache["k"].shape[-2:]
-    quantized = "k_scale" in kv_cache
-    out = dict(kv_cache)
-    for leaf, new in (("k", k_new), ("v", v_new)):
-        src = new.reshape(-1, Kh, hd)
-        pool = kv_cache[leaf]
-        if quantized:
-            src, scales = quant.quantize(src, pool.dtype)
-            sp = kv_cache[leaf + "_scale"]
-            out[leaf + "_scale"] = sp.reshape(num_blocks * bs, Kh) \
-                .at[widx_flat].set(scales) \
-                .reshape(num_blocks, bs, Kh)
-        out[leaf] = pool.reshape(num_blocks * bs, Kh, hd) \
-            .at[widx_flat].set(src.astype(pool.dtype)) \
-            .reshape(num_blocks, bs, Kh, hd)
-    out["pos"] = kv_cache["pos"].reshape(-1).at[widx_flat].set(
-        positions.reshape(-1)).reshape(num_blocks, bs)
-    out["seg"] = kv_cache["seg"].reshape(-1).at[widx_flat].set(
-        segments.reshape(-1)).reshape(num_blocks, bs)
+    with jax.named_scope("kv_write"):
+        Kh, hd = kv_cache["k"].shape[-2:]
+        quantized = "k_scale" in kv_cache
+        out = dict(kv_cache)
+        for leaf, new in (("k", k_new), ("v", v_new)):
+            src = new.reshape(-1, Kh, hd)
+            pool = kv_cache[leaf]
+            if quantized:
+                src, scales = quant.quantize(src, pool.dtype)
+                sp = kv_cache[leaf + "_scale"]
+                out[leaf + "_scale"] = sp.reshape(num_blocks * bs, Kh) \
+                    .at[widx_flat].set(scales) \
+                    .reshape(num_blocks, bs, Kh)
+            out[leaf] = pool.reshape(num_blocks * bs, Kh, hd) \
+                .at[widx_flat].set(src.astype(pool.dtype)) \
+                .reshape(num_blocks, bs, Kh, hd)
+        out["pos"] = kv_cache["pos"].reshape(-1).at[widx_flat].set(
+            positions.reshape(-1)).reshape(num_blocks, bs)
+        out["seg"] = kv_cache["seg"].reshape(-1).at[widx_flat].set(
+            segments.reshape(-1)).reshape(num_blocks, bs)
     return out
 
 
@@ -120,12 +122,14 @@ def _gather_dequant(new_cache, leaf, slot, num_blocks: int, bs: int, shape,
     """Gather pool slots ``slot`` of ``leaf`` ('k'/'v') and, on a
     quantized pool, dequantize post-gather (the XLA fallback path — the
     Pallas kernels dequantize in-kernel instead)."""
-    flat = new_cache[leaf].reshape(num_blocks * bs, *shape)
-    g = flat[slot]
-    if leaf + "_scale" not in new_cache:
-        return g
-    sc = new_cache[leaf + "_scale"].reshape(num_blocks * bs, shape[0])[slot]
-    return quant.dequantize(g, sc, dtype)
+    with jax.named_scope("kv_gather"):
+        flat = new_cache[leaf].reshape(num_blocks * bs, *shape)
+        g = flat[slot]
+        if leaf + "_scale" not in new_cache:
+            return g
+        sc = new_cache[leaf + "_scale"].reshape(num_blocks * bs,
+                                                shape[0])[slot]
+        return quant.dequantize(g, sc, dtype)
 
 
 def make_paged_decode_override(block_tables, num_blocks: int, bs: int):
@@ -155,9 +159,11 @@ def make_paged_decode_override(block_tables, num_blocks: int, bs: int):
         segg = new_cache["seg"].reshape(-1)[slot]
         live = jnp.repeat(bt >= 0, bs, axis=1)
         segg = jnp.where(live, segg, -1)
-        o = attention(q, kg, vg, q_positions=positions, kv_positions=posg,
-                      q_segments=segments, kv_segments=segg,
-                      window=cfg.sliding_window, q_block=opts.q_block)
+        with jax.named_scope("paged_attention"):
+            o = attention(q, kg, vg, q_positions=positions,
+                          kv_positions=posg, q_segments=segments,
+                          kv_segments=segg, window=cfg.sliding_window,
+                          q_block=opts.q_block)
         return o, new_cache
 
     return override
@@ -179,11 +185,12 @@ def make_fused_decode_override(block_tables, num_blocks: int, bs: int,
         widx = _flat_write_idx(bt, positions, bs, num_blocks * bs)
         new_cache = _write_kv(kv_cache, widx.reshape(-1), k_new, v_new,
                               positions, segments, num_blocks, bs)
-        o = ops.fused_paged_decode(
-            q, new_cache["k"], new_cache["v"], new_cache["seg"],
-            new_cache["pos"], segments, positions, bt,
-            k_scale=new_cache.get("k_scale"),
-            v_scale=new_cache.get("v_scale"), config=fused_cfg)
+        with jax.named_scope("paged_attention"):
+            o = ops.fused_paged_decode(
+                q, new_cache["k"], new_cache["v"], new_cache["seg"],
+                new_cache["pos"], segments, positions, bt,
+                k_scale=new_cache.get("k_scale"),
+                v_scale=new_cache.get("v_scale"), config=fused_cfg)
         return o.astype(q.dtype), new_cache
 
     return override
@@ -236,10 +243,11 @@ def make_paged_verify_override(q_rows, block_tables, block_ids, block_owner,
         slot_seg = new_cache["seg"].reshape(-1)[slot]
         segg = jnp.where((slot_seg >= 0) & (jnp.repeat(owner, bs) >= 0),
                          jnp.repeat(owner, bs), -1)[None]
-        o = attention(q, kg, vg, q_positions=positions, kv_positions=posg,
-                      q_segments=segments, kv_segments=segg,
-                      window=cfg.sliding_window, q_block=opts.q_block,
-                      q_anc=anc, kv_node=node)
+        with jax.named_scope("paged_attention"):
+            o = attention(q, kg, vg, q_positions=positions,
+                          kv_positions=posg, q_segments=segments,
+                          kv_segments=segg, window=cfg.sliding_window,
+                          q_block=opts.q_block, q_anc=anc, kv_node=node)
         return o, new_cache
 
     return override
@@ -271,11 +279,12 @@ def make_fused_verify_override(q_rows, block_tables, block_ids, block_owner,
         new_cache = _write_kv(kv_cache, widx.reshape(-1), k_new, v_new,
                               positions, jnp.zeros_like(segments),
                               num_blocks, bs)
-        o = ops.fused_paged_verify(
-            q[0], new_cache["k"], new_cache["v"], new_cache["seg"],
-            new_cache["pos"], segments[0], pos, ids, owner, anc, node,
-            k_scale=new_cache.get("k_scale"),
-            v_scale=new_cache.get("v_scale"), config=fused_cfg)
+        with jax.named_scope("paged_attention"):
+            o = ops.fused_paged_verify(
+                q[0], new_cache["k"], new_cache["v"], new_cache["seg"],
+                new_cache["pos"], segments[0], pos, ids, owner, anc, node,
+                k_scale=new_cache.get("k_scale"),
+                v_scale=new_cache.get("v_scale"), config=fused_cfg)
         return o[None].astype(q.dtype), new_cache
 
     return override

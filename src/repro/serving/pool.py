@@ -141,6 +141,13 @@ class DenseCachePool:
     def can_admit(self, length: int) -> bool:
         return bool(self._free)
 
+    def cells(self) -> Tuple[int, int, int]:
+        """(used, held, alloc) KV cells: the live rows' lengths, the rows
+        they hold at ``max_len`` each, and the whole grid."""
+        used = int(sum(self.lengths[r] for r in self.row_of.values()))
+        return (used, len(self.row_of) * self.max_len,
+                self.capacity * self.max_len)
+
     def insert(self, rid: int, one_cache, length: int, last_token: int):
         row = self._free.pop()
         self.cache = self._row_set(self.cache, row, one_cache)
@@ -395,6 +402,15 @@ class PagedCachePool:
 
     def allocated_cells(self, rid: int) -> int:
         return int(self._nb[self.row_of[rid]]) * self.block_size
+
+    def cells(self) -> Tuple[int, int, int]:
+        """(used, held, alloc) KV cells: the live rows' lengths, the
+        blocks held (``num_blocks`` minus free) and every block, each at
+        ``block_size`` cells."""
+        used = int(sum(self.lengths[r] for r in self.row_of.values()))
+        bs = self.block_size
+        return (used, (self.num_blocks - len(self._free_blocks)) * bs,
+                self.num_blocks * bs)
 
     def prefill_len(self, src_len: int) -> int:
         """Block-aligned cache length the engine should prefill with before

@@ -30,15 +30,53 @@ stamp every committed token's sim-clock time onto
 each request's :class:`~repro.data.workloads.SLO` contract into the
 headline serving metric — tokens that met their deadline per second,
 the figure an operator with latency contracts actually buys.
+
+Host spans: :func:`span` opens a ``jax.profiler.TraceAnnotation``, so
+the engine's phases land in the same trace as the device's operations,
+on the profiler's clock.  With no trace being recorded a span costs one
+native call; its counters (``meta`` callables) are computed only while
+one is.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Iterable
+from typing import Callable, Iterable, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.data.workloads import Request
+
+
+class _Off:
+    """The span handed out while no trace is recorded: entering it,
+    leaving it and annotating it do nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_OFF = _Off()
+
+
+def span(name: str, meta: Optional[Callable[[], dict]] = None):
+    """A host span ``name`` over a ``with`` block.  ``meta`` returns the
+    span's counters and is called only while a trace is recorded."""
+    if not TraceAnnotation.is_enabled():
+        return _OFF
+    return TraceAnnotation(name, **meta()) if meta else TraceAnnotation(name)
+
+
+def annotate(s, meta: Callable[[], dict]):
+    """Attach ``meta()``'s counters to the open span ``s`` once they are
+    known; nothing is computed for a span opened with tracing off."""
+    if s is not _OFF:
+        s.set_metadata(**meta())
 
 
 @dataclasses.dataclass(frozen=True)
@@ -220,4 +258,5 @@ __all__ = [
     "SchedulerStats", "EngineStats", "ReplicaStats", "FleetStats",
     "SLOSummary", "slo_summary", "min_outstanding_deadline",
     "slo_headroom", "expected_time_per_token", "DEADLINE_HORIZON",
+    "span", "annotate",
 ]

@@ -204,7 +204,10 @@ def models():
 
 
 def _run_engine(llm, ssms, layout, prefill_chunk, *, token_budget=None,
-                kv_budget=None, capacity=4, reqs=None, max_slots=400):
+                kv_budget=None, capacity=4, reqs=None, max_slots=400,
+                slots=None):
+    """Serve ``reqs`` to the end; ``slots``, a list, collects the
+    records ``step()`` returns."""
     sel = LBSS(SelectorConfig(n_ssms=len(ssms),
                               batch_limits=[capacity] * len(ssms),
                               alpha=4, beta=2, seed=1))
@@ -219,7 +222,12 @@ def _run_engine(llm, ssms, layout, prefill_chunk, *, token_budget=None,
         reqs = make_workload("mix", 4, VOCAB, seed=7, scale=0.25,
                              arrival_rate=400.0)
     eng.add_requests(reqs)
-    eng.run(max_slots=max_slots)
+    for _ in range(max_slots):
+        rec = eng.step()
+        if slots is not None:
+            slots.append(rec)
+        if rec.get("done") and not eng.scheduler.outstanding:
+            break
     assert all(r.done for r in eng.requests.values())
     return eng
 
@@ -267,11 +275,12 @@ def test_mixed_slots_stay_greedy_exact_under_preemption(models):
     reqs.append(Request(rid=len(reqs), dataset="long", difficulty=0.5,
                         prompt=rng.integers(0, VOCAB, 24).astype(np.int32),
                         max_new=8, arrival=0.01, emitted=[]))
+    slots = []
     eng = _run_engine(llm, ssms, "paged", 8, token_budget=24, kv_budget=80,
-                      capacity=3, reqs=reqs, max_slots=600)
+                      capacity=3, reqs=reqs, max_slots=600, slots=slots)
     assert eng.scheduler.preemptions > 0, "budget never bound: tune test"
     assert eng.scheduler.prefill_grants > 0
-    mixed = sum(1 for rec in eng.slot_log
+    mixed = sum(1 for rec in slots
                 if rec.get("prefill_tokens") and rec.get("active"))
     assert mixed > 0, "no slot ran chunk-prefill and decode together"
     for r in eng.requests.values():

@@ -134,10 +134,6 @@ def reference_tokens(models, seed):
     return _REFERENCE[seed]
 
 
-def sim_stats(stats: dict) -> dict:
-    return {k: v for k, v in stats.items() if k != "wall_time"}
-
-
 # ------------------------------------------------------- chaos harness --
 
 
@@ -317,7 +313,7 @@ def test_autoscale_off_bit_identity(models, ekw):
 
     for rid, r in bare.requests.items():
         assert routed.requests[rid].emitted == r.emitted, rid
-    assert sim_stats(rstats["replica_stats"][0]) == sim_stats(bare_stats)
+    assert rstats["replica_stats"][0] == bare_stats
     assert rstats["makespan_sim"] == bare_stats["sim_time"]
     assert rstats["steals"] == 0
     assert rstats["scale_ups"] == 0 and rstats["scale_downs"] == 0
@@ -338,9 +334,7 @@ def test_default_config_is_autoscale_off(models):
         st_ = router.run(max_slots=300)
         results.append((dict(router.dispatched_to), st_))
     assert results[0][0] == results[1][0]
-    a = [sim_stats(s) for s in results[0][1]["replica_stats"]]
-    b = [sim_stats(s) for s in results[1][1]["replica_stats"]]
-    assert a == b
+    assert results[0][1]["replica_stats"] == results[1][1]["replica_stats"]
     assert results[0][1]["accepted_tokens"] == results[1][1]["accepted_tokens"]
 
 

@@ -68,12 +68,6 @@ def workload(n=6, rate=300.0, seed=11):
     return make_workload("mix", n, VOCAB, seed=seed, scale=0.25, arrival_rate=rate)
 
 
-def sim_stats(stats: dict) -> dict:
-    """Engine stats minus host wall-clock (recorded for reference only —
-    every sim-clock metric must be bit-identical)."""
-    return {k: v for k, v in stats.items() if k != "wall_time"}
-
-
 # ------------------------------------------------------- N=1 bit-identity --
 
 
@@ -95,7 +89,7 @@ def test_single_replica_router_bit_identical(models, policy):
         assert routed.requests[rid].emitted == r.emitted, rid
     # the full engine stats dict — goodput, latency percentiles, TTFT,
     # switch and scheduler counters — must match field for field
-    assert sim_stats(rstats["replica_stats"][0]) == sim_stats(bare_stats)
+    assert rstats["replica_stats"][0] == bare_stats
     assert rstats["accepted_tokens"] == bare_stats["accepted_tokens"]
     assert rstats["makespan_sim"] == bare_stats["sim_time"]
     assert rstats["dispatched"] == [len(reqs)]
@@ -122,7 +116,7 @@ def test_single_replica_bit_identical_chunked_adaptive(models):
 
     for rid, r in bare.requests.items():
         assert routed.requests[rid].emitted == r.emitted, rid
-    assert sim_stats(rstats["replica_stats"][0]) == sim_stats(bare_stats)
+    assert rstats["replica_stats"][0] == bare_stats
 
 
 # ------------------------------------------------------------ conservation --
