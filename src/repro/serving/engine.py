@@ -78,6 +78,29 @@ def _bucket(n: int, align: int = 16) -> int:
     return max(align, int(math.ceil(n / align) * align))
 
 
+def catchup_rows(ssm_rows: Dict, llm_rows: Dict, capacity: int) -> np.ndarray:
+    """Target pool row of each drafter pool row's request: -1 for a
+    drafter row that holds no request, or whose request holds no target
+    row."""
+    rows = np.full(capacity, -1, np.int32)
+    for rid, row in ssm_rows.items():
+        rows[row] = llm_rows.get(rid, -1)
+    return rows
+
+
+@jax.jit
+def catchup_inputs(out_all, n_acc_all, rows):
+    """A drafter pool's catch-up input, gathered on the device from
+    verify's per-row results: drafter row r takes target row ``rows[r]``'s
+    emitted tokens and accepted count, zeros where ``rows[r]`` is -1
+    (``catchup_rows``)."""
+    hit = rows >= 0
+    src = jnp.maximum(rows, 0)
+    outs = jnp.where(hit[:, None], out_all[src], 0).astype(jnp.int32)
+    nacc = jnp.where(hit, n_acc_all[src], 0).astype(jnp.int32)
+    return outs, nacc
+
+
 @dataclasses.dataclass(kw_only=True)
 class EngineConfig:
     """Keyword-only on purpose (like ``SchedulerConfig``): fields are
@@ -379,6 +402,7 @@ class SpinEngine:
         self.verify_tokens_total = 0       # LLM verify query tokens issued
         self.tree_forks = 0                # CoW row forks (tree mode)
         self.tree_adoptions = 0            # slots won by a non-main branch
+        self.reads = 0                     # device->host reads this step
         self.prefill_tokens_total = 0
         self.straggler_redispatches = 0
         # per request: (sum, count) of its per-slot acceptance rates
@@ -571,7 +595,8 @@ class SpinEngine:
         bit-exactness contract between them cannot drift."""
         if r.emitted:
             return int(r.emitted[-1])
-        last = int(jnp.argmax(logits[0, idx, :self.llm.cfg.vocab_size]))
+        last = int(self._read(
+            jnp.argmax(logits[0, idx, :self.llm.cfg.vocab_size])))
         r.emitted = [last]
         if r.host_first_token is None:
             r.host_first_token = time.perf_counter()
@@ -718,16 +743,26 @@ class SpinEngine:
     def _step_meta(self, rec: dict) -> dict:
         used, held, alloc = self.kv_cells()
         return {"rows": rec.get("active", 0),
+                "reads": self.reads,
                 "waiting": len(self.scheduler.waiting),
                 "kv_used": used, "kv_held": held, "kv_alloc": alloc}
 
     def step(self) -> dict:
         """One slot (module docstring), inside the ``spin.step`` span;
         ``docs/SERVING.md`` lists the span tree."""
+        self.reads = 0
         with span("spin.step") as s:
             rec = self._step()
             annotate(s, lambda: self._step_meta(rec))
         return rec
+
+    def _read(self, x):
+        """Copy the device array (or tuple of arrays) ``x`` to the host.
+        Every read the engine makes goes through here and is counted in
+        ``reads``: a step reads each drafter's candidates and verify's
+        results once, plus one first token per finished prefill."""
+        self.reads += 1
+        return jax.device_get(x)
 
     def _step(self) -> dict:
         self._schedule(grant_prefill=True)
@@ -1008,14 +1043,14 @@ class SpinEngine:
                                       width, k, block_tables=bt,
                                       fused_cfg=self.fused_cfg)
             pool.cache = cache
-            return np.asarray(cand)
+            return self._read(cand)
         cand, _, cache = sd.draft(b, pool.cache, tok, lengths,
                                   width, k)
         pool.cache = cache
         idle = [row for row in range(pool.capacity)
                 if row not in pool.row_of.values()]
         pool.invalidate_rows(idle)
-        return np.asarray(cand)
+        return self._read(cand)
 
     # ----------------------------------------------------- tree helpers --
     @staticmethod
@@ -1086,7 +1121,7 @@ class SpinEngine:
         pool.cache = cache
         for brid in forked:
             pool.evict(brid)
-        return np.asarray(cand), branch_map
+        return self._read(cand), branch_map
 
     def _tree_block_maps(self, ids_np, owner_np, tree_rows, W: int):
         """Per-slot tree metadata for the packed gather: block owners of
@@ -1193,7 +1228,7 @@ class SpinEngine:
             cand = jnp.asarray(cand)
 
             if self.ecfg.use_packed_verify:
-                logits = self._verify_packed(cand, lengths, last, W,
+                logits = self._verify_packed(cand, last, W,
                                              tree_rows=tree_rows)
             else:
                 inp = jnp.concatenate([last, cand], axis=1)
@@ -1230,8 +1265,10 @@ class SpinEngine:
             # the LLM argmax can accept), so ties land on branch 0 and the
             # bonus token is the LLM's own pick — lossless at any shape.
             winner_row = {rid: row for rid, row in zip(ids, rows)}
+            host = None
             if self.tree:
-                n_acc_np = np.asarray(n_acc_all)
+                host = self._read((out_all, n_acc_all))
+                _, n_acc_np = host
                 for rid in ids:
                     best_j, best_row = 0, winner_row[rid]
                     for (jj, brid, brow) in fork_rows[rid]:
@@ -1284,44 +1321,41 @@ class SpinEngine:
                         jnp.asarray(hi, jnp.int32))
 
         with span("spin.catchup"):
-            # per-SSM catch-up (fill the c_k hole) + rollback on draft pools
+            # per-SSM catch-up (fill the c_k hole) + rollback on draft
+            # pools; the inputs are gathered from verify's results on the
+            # device, so the catch-up queues behind verify with no host
+            # round trip
             for j, pool in enumerate(self.ssm_pools):
                 if not pool.row_of:
                     continue
                 pl = jnp.asarray(pool.lengths, jnp.int32)
-                outs_j = np.zeros((pool.capacity, W + 1), np.int32)
-                nacc_j = np.zeros(pool.capacity, np.int64)
-                for rid, row in pool.row_of.items():
-                    lrow = self.llm_pool.row_of.get(rid)
-                    if lrow is None:
-                        continue
-                    outs_j[row] = np.asarray(out_all[lrow])
-                    nacc_j[row] = int(n_acc_all[lrow])
+                outs_j, nacc_j = catchup_inputs(
+                    out_all, n_acc_all, jnp.asarray(catchup_rows(
+                        pool.row_of, self.llm_pool.row_of, pool.capacity)))
                 if self.paged:
                     bt, _ = pool.block_table_array()
                     _, pool.cache = self.ssms[j].decode_paged(
-                        pool.cache, jnp.asarray(outs_j), pl + 1, bt,
-                        self.fused_cfg)
-                    pool.invalidate_span(
-                        pl + 2 + jnp.asarray(nacc_j, jnp.int32),
-                        pl + W + 3, W=W + 1)
+                        pool.cache, outs_j, pl + 1, bt, self.fused_cfg)
+                    pool.invalidate_span(pl + 2 + nacc_j, pl + W + 3,
+                                         W=W + 1)
                 else:
                     _, pool.cache = self.ssms[j].decode(
-                        pool.cache, jnp.asarray(outs_j), pl + 1)
+                        pool.cache, outs_j, pl + 1)
                     pool.cache = sd.invalidate_slots_jit(
-                        pool.cache, pl + 2 + jnp.asarray(nacc_j, jnp.int32),
-                        pl + W + 3)
+                        pool.cache, pl + 2 + nacc_j, pl + W + 3)
+            # verify's results reach the host once a step, while the
+            # catch-up runs (tree mode read them already, to adopt)
+            if host is None:
+                host = self._read((out_all, n_acc_all))
 
         with span("spin.commit"):
             # update lengths / last tokens on pools
-            n_acc = np.zeros(len(ids), np.int64)
-            out = np.zeros((len(ids), W + 1), np.int64)
-            out_len = np.zeros(len(ids), np.int64)
-            for i, rid in enumerate(ids):
-                row = winner_row[rid]
-                n_acc[i] = int(n_acc_all[row])
-                out[i] = np.asarray(out_all[row])
-                out_len[i] = n_acc[i] + 1
+            out_np, n_acc_np = host
+            win = np.asarray([winner_row[rid] for rid in ids], np.int64)
+            n_acc = n_acc_np[win].astype(np.int64)
+            out = out_np[win].astype(np.int64)
+            out_len = n_acc + 1
+            for i, (rid, row) in enumerate(zip(ids, win)):
                 self.llm_pool.lengths[row] += out_len[i]
                 self.llm_pool.last_token[row] = out[i, n_acc[i]]
                 j = self.assignment[rid]
@@ -1330,7 +1364,7 @@ class SpinEngine:
                 self.ssm_pools[j].last_token[srow] = out[i, n_acc[i]]
             return n_acc, out, out_len
 
-    def _verify_packed(self, cand, lengths, last, W: int, tree_rows=None):
+    def _verify_packed(self, cand, last, W: int, tree_rows=None):
         """Packed verification via request decomposition (§V-A) at the
         slot's max granted depth W.  Paged: the packed KV is the cohort's
         live blocks, gathered fragment-by-fragment from the pool — no flat
@@ -1367,7 +1401,7 @@ class SpinEngine:
                     self.fused_cfg)
             self.llm_pool.cache = cache
             return logits[0].reshape(N, W + 1, -1)
-        lens_np = np.maximum(np.asarray(lengths), 1)
+        lens_np = np.maximum(np.asarray(self.llm_pool.lengths), 1)
         plan = D.plan_decomposition(
             [int(n) for n in lens_np],
             align=min(128, _bucket(int(lens_np.max()), 16)))

@@ -11,7 +11,8 @@ from repro.core import spec_decode as sd
 from repro.core.selector import LBSS, SelectorConfig
 from repro.data.workloads import make_workload
 from repro.models import transformer as T
-from repro.serving.engine import EngineConfig, SpinEngine
+from repro.serving.engine import (EngineConfig, SpinEngine, catchup_inputs,
+                                  catchup_rows)
 
 VOCAB = 256
 
@@ -104,3 +105,31 @@ def test_straggler_mitigation_bounds_makespan(models):
     assert e1.straggler_redispatches > 0
     for r in e1.requests.values():
         assert r.done
+
+
+def test_catchup_input_gathered_on_device_matches_per_row_reads():
+    """The drafters' catch-up input, gathered on the device, is what
+    reading verify's results row by row built: drafter and target rows in
+    different orders, a drafter row whose request holds no target row, and
+    empty drafter rows all read zeros."""
+    rng = np.random.default_rng(0)
+    N, W, cap = 6, 4, 5
+    out_all = jnp.asarray(rng.integers(1, VOCAB, (N, W + 1)), jnp.int32)
+    n_acc_all = jnp.asarray(rng.integers(0, W + 1, N), jnp.int32)
+    llm_rows = {10: 4, 11: 0, 12: 5, 13: 2}
+    ssm_rows = {12: 0, 10: 3, 14: 1, 11: 4}       # 14: no target row
+    want_outs = np.zeros((cap, W + 1), np.int32)
+    want_nacc = np.zeros(cap, np.int64)
+    for rid, row in ssm_rows.items():
+        lrow = llm_rows.get(rid)
+        if lrow is None:
+            continue
+        want_outs[row] = np.asarray(out_all[lrow])
+        want_nacc[row] = int(n_acc_all[lrow])
+    rows = catchup_rows(ssm_rows, llm_rows, cap)
+    assert rows.tolist() == [5, -1, -1, 4, 0]
+    outs, nacc = catchup_inputs(out_all, n_acc_all, jnp.asarray(rows))
+    assert outs.dtype == nacc.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(outs), want_outs)
+    np.testing.assert_array_equal(np.asarray(nacc), want_nacc)
+    assert want_outs[1].sum() == want_outs[2].sum() == 0

@@ -136,6 +136,34 @@ def test_spans_carry_their_counters(traced):
     assert all("switches" in m for n, _, _, m in spans if n == "spin.assign")
 
 
+def fresh(n, seed):
+    """``n`` requests due at once, each with room for many steps."""
+    rng = np.random.default_rng(seed)
+    return [Request(rid=i, dataset="t", difficulty=0.5,
+                    prompt=rng.integers(0, VOCAB, 10 + i).astype(np.int32),
+                    max_new=24, emitted=[]) for i in range(n)]
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"kv_layout": "dense", "use_packed_verify": False}],
+    ids=["paged-packed", "dense-padded"])
+def test_a_step_reads_to_the_host_once_per_drafter_and_once_for_verify(
+        models, tmp_path, kw):
+    """``spin.step``'s ``reads`` is the drafter's candidates plus verify's
+    results, at 2 rows as at 8: no read per row."""
+    engines = [engine(models, capacity=8, **kw) for _ in range(2)]
+    with jax.profiler.trace(str(tmp_path)):
+        for eng, n in zip(engines, (2, 8)):
+            eng.add_requests(fresh(n, seed=n))
+            for _ in range(2):
+                assert eng.step()["active"] == n
+                assert eng.reads == len(eng.ssms) + 1
+    steps = [m for name, _, _, m in host_spans(str(tmp_path))
+             if name == "spin.step"]
+    assert [m["rows"] for m in steps] == [2, 2, 8, 8]
+    assert [m["reads"] for m in steps] == [2] * 4
+
+
 def test_no_trace_no_counters(models, monkeypatch):
     """With no trace recorded, a span computes nothing: its counters'
     callables are never called."""

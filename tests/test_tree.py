@@ -23,7 +23,7 @@ import pytest
 from repro.configs import registry
 from repro.core import spec_decode as sd
 from repro.core.selector import LBSS, SelectorConfig
-from repro.data.workloads import make_workload
+from repro.data.workloads import Request, make_workload
 from repro.models import transformer as T
 from repro.serving.engine import EngineConfig, SpinEngine
 
@@ -125,6 +125,29 @@ def test_tree_branch2_lossless_and_drains_blocks(models):
             f"request {r.rid} diverged from plain greedy decode"
     # every CoW fork released its references: nothing leaked
     assert eng.llm_pool.free_blocks == eng.llm_pool.num_blocks
+
+
+def test_a_tree_step_reads_verify_once_at_any_row_count(models):
+    """A tree step reads each drafting drafter's candidates and verify's
+    results once (adoption and commit share the one copy), at 2 rows as
+    at 8."""
+    llm, ssms = models
+    rng = np.random.default_rng(5)
+    for n in (2, 8):
+        sel = LBSS(SelectorConfig(n_ssms=2, batch_limits=[8, 8], alpha=4,
+                                  beta=2, seed=1))
+        eng = SpinEngine(llm, ssms, sel, EngineConfig(
+            gamma=3, max_len=128, capacity=8, packed_bucket=128,
+            spec_shape="tree", spec_branch=2))
+        eng.add_requests([
+            Request(rid=i, dataset="t", difficulty=0.5,
+                    prompt=rng.integers(0, VOCAB, 10 + i).astype(np.int32),
+                    max_new=24, emitted=[]) for i in range(n)])
+        for _ in range(2):
+            assert eng.step()["active"] == n
+            drafting = {eng.assignment[rid] for rid in range(n)}
+            assert eng.reads == len(drafting) + 1
+        assert eng.tree_forks > 0
 
 
 def test_tree_adaptive_gamma_lossless(models):
