@@ -1,18 +1,20 @@
 """Build the system under test through the program's own constructors:
 its ``ModelConfig``, ``spec_decode.Bundle``, ``launch/serve.make_selector``,
-``EngineConfig`` and ``SpinEngine``.  The weights are the benchmark's
-(``harness.weights``), made on the device in one jitted call per model
-from the seed.  Import this module only after JAX is set up: it imports
-the program from ``src/`` beside ``bench/``."""
+``EngineConfig`` and ``SpinEngine``.  Each model is of the architecture
+its configuration names (``bench/archs/<model_type>.py``, found by
+``spec.load_arch``), whose weights are made on the device in one jitted
+call per model from the seed.  Import this module only after JAX is set
+up: it imports the program from ``src/`` beside ``bench/``."""
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 import sys
 
-from harness.spec import ROOT
-from harness.weights import Qwen2, model_key, program_params
+from harness.spec import ROOT, load_arch
+from harness.weights import model_key
 
 
 def import_program(root: str = ROOT):
@@ -24,43 +26,52 @@ def import_program(root: str = ROOT):
         sys.path.insert(0, src)
 
 
-def models(cfg: dict):
-    """(target, [drafters]) as ``Qwen2`` sizes from a configuration file."""
-    target = Qwen2.from_hf(cfg["name"] + ".target", cfg)
-    drafters = [Qwen2.from_hf(f"{cfg['name']}.drafter{i}", d)
+@dataclasses.dataclass(frozen=True)
+class Model:
+    """One model of a configuration: its architecture's module and the
+    sizes that module read from the configuration."""
+    arch: object
+    sizes: object
+
+
+def models(cfg: dict, root: str = ROOT):
+    """(target, [drafters]) as ``Model``s from a configuration file.  A
+    drafter without a ``model_type`` of its own is of the target's."""
+    if "model_type" not in cfg:
+        raise KeyError(f"{cfg['name']}: the configuration names no "
+                       "model_type")
+
+    def model(name, hf, model_type):
+        arch = load_arch(model_type, root)
+        return Model(arch, arch.sizes(name, hf))
+
+    target = model(cfg["name"] + ".target", cfg, cfg["model_type"])
+    drafters = [model(f"{cfg['name']}.drafter{i}", d,
+                      d.get("model_type", cfg["model_type"]))
                 for i, d in enumerate(cfg["drafters"])]
     for m in drafters:
-        if m.vocab != target.vocab:
-            raise ValueError(f"{m.name} has vocab {m.vocab}, the target "
-                             f"{target.vocab}: drafts are verified token "
-                             "for token")
+        if m.sizes.vocab != target.sizes.vocab:
+            raise ValueError(f"{m.sizes.name} has vocab {m.sizes.vocab}, the "
+                             f"target {target.sizes.vocab}: drafts are "
+                             "verified token for token")
     return target, drafters
 
 
-def model_config(m: Qwen2, dtype: str = "bfloat16"):
-    from repro.models.config import ATTN, ModelConfig
-    return ModelConfig(name=m.name, family="dense", n_layers=m.layers,
-                       d_model=m.hidden, n_heads=m.heads,
-                       n_kv_heads=m.kv_heads, d_ff=m.inter,
-                       vocab_size=m.vocab, head_dim=m.head_dim,
-                       qkv_bias=True, tie_embeddings=m.tied, unit=(ATTN,),
-                       rope_theta=m.theta, norm_eps=m.eps, dtype=dtype)
-
-
 @functools.lru_cache(maxsize=None)
-def _maker(m: Qwen2, padded_vocab: int):
+def _maker(m: Model, padded_vocab: int):
     import jax
-    return jax.jit(lambda key: program_params(m, key, padded_vocab))
+    return jax.jit(lambda key: m.arch.program_params(m.sizes, key,
+                                                     padded_vocab))
 
 
-def make_bundle(m: Qwen2, seed: int, index: int, dtype: str = "bfloat16"):
+def make_bundle(m: Model, seed: int, index: int, dtype: str = "bfloat16"):
     """A ``spec_decode.Bundle`` holding model ``index``'s weights, made
     from the seed on the device; checked against the tree the program's
     ``abstract_params`` describes."""
     import jax
     from repro.core import spec_decode as sd
     from repro.models import transformer as T
-    mc = model_config(m, dtype)
+    mc = m.arch.model_config(m.sizes, dtype)
     params = _maker(m, mc.padded_vocab)(model_key(seed, index))
     if dtype != "bfloat16":
         params = jax.tree.map(lambda a: a.astype(dtype), params)
@@ -68,7 +79,7 @@ def make_bundle(m: Qwen2, seed: int, index: int, dtype: str = "bfloat16"):
                         T.abstract_params(mc))
     got = jax.tree.map(lambda a: (a.shape, str(a.dtype)), params)
     if want != got:
-        raise ValueError(f"{m.name}: weight tree differs from the "
+        raise ValueError(f"{m.sizes.name}: weight tree differs from the "
                          f"program's: {got} != {want}")
     return sd.Bundle(mc, params)
 

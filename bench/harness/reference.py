@@ -1,32 +1,31 @@
-"""The plain reference: a Qwen2 forward pass in float32 at the highest
-matmul precision, written from the published architecture (RMSNorm,
-rotary embeddings over halves, grouped-query attention with QKV bias,
-SwiGLU MLP, tied or untied head).  It imports nothing of the program and
-makes its own weights again from the seed (``harness.weights``), one
-layer at a time inside the loop, so it holds no more than one layer's
-weights.  Attention runs in blocks of queries and the head in blocks of
-positions, so a batch of long sequences fits beside nothing else.
+"""The plain reference: a float32 forward pass at the highest matmul
+precision, written from each published architecture.  The architecture's
+module (``bench/archs/<model_type>.py``) runs its stack in
+``reference_forward`` and may use the blocks below (RMSNorm, rotary
+embeddings over halves, causal attention in blocks of queries); this
+module scores the tokens.  It imports nothing of the program and makes
+its own weights again from the seed, one layer at a time inside the
+architecture's layer loop.  The head runs in blocks of positions, so a
+batch of long sequences fits beside nothing else.
 
 ``gaps`` teacher-forces the served tokens: at each scored position it
 returns the reference's best logit minus its logit for the token that
 followed there (0 where the served token is the reference's best).
-With ``control`` it runs a second forward whose matrices are rounded to
-float8 (e4m3, one scale per output column) and returns, beside the
-gaps, the reference's gap for the token the float8 forward puts first:
-what a server in that precision would have served."""
+With ``control`` the architecture runs a second forward whose matrices
+are rounded to float8 (e4m3, one scale per output column, ``fp8_round``)
+and this module returns, beside the gaps, the reference's gap for the
+token the float8 forward puts first: what a server in that precision
+would have served."""
 
 from __future__ import annotations
 
 import functools
 
-from harness.weights import Qwen2, global_weights, layer_weights
-
 Q_BLOCK = 256
 ROW_BLOCK = 256
-MATRICES = ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
 
 
-def _fp8_round(w):
+def fp8_round(w):
     """Round a float32 matrix (input-major) to float8 e4m3 with one
     scale per output column, and back to float32."""
     import jax.numpy as jnp
@@ -35,33 +34,49 @@ def _fp8_round(w):
     return (w / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _forward_layer(model: Qwen2, w: dict, x, positions):
+def rounded(w: dict, matrices) -> dict:
+    """The control's copy of a layer's leaves: ``matrices`` rounded to
+    float8, the rest as they are."""
+    return {n: (fp8_round(a) if n in matrices else a) for n, a in w.items()}
+
+
+def dot(a, b):
+    import jax.numpy as jnp
+    from jax import lax
+    return jnp.dot(a, b, precision=lax.Precision.HIGHEST)
+
+
+def rms_norm(h, g, eps: float):
+    """RMSNorm with the gain stored as ``gain - 1``."""
+    import jax.numpy as jnp
+    from jax import lax
+    var = jnp.mean(h * h, axis=-1, keepdims=True)
+    return h * lax.rsqrt(var + eps) * (1.0 + g)
+
+
+def rope(t, positions, theta: float):
+    """Rotary embedding over halves of the last axis of ``t`` (B, S, H,
+    hd) at ``positions`` (B, S)."""
+    import jax.numpy as jnp
+    half = t.shape[-1] // 2
+    inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
+    ang = positions[:, :, None, None].astype(jnp.float32) * inv
+    c, s = jnp.cos(ang), jnp.sin(ang)
+    t1, t2 = t[..., :half], t[..., half:]
+    return jnp.concatenate([t1 * c - t2 * s, t2 * c + t1 * s], axis=-1)
+
+
+def causal_attention(q, k, v, positions):
+    """Grouped-query causal attention: q (B, S, nq, hd), k and v (B, S,
+    nkv, hd); returns (B, S, nq * hd).  Queries go in blocks of
+    ``Q_BLOCK``, so S must be a multiple of it."""
     import jax
     import jax.numpy as jnp
     from jax import lax
     hi = lax.Precision.HIGHEST
-    B, S, d = x.shape
-    nq, nkv, hd = model.heads, model.kv_heads, model.head_dim
+    B, S, nq, hd = q.shape
+    nkv = k.shape[2]
     G = nq // nkv
-
-    def norm(h, g):
-        var = jnp.mean(h * h, axis=-1, keepdims=True)
-        return h * lax.rsqrt(var + model.eps) * (1.0 + g)
-
-    def rope(t):
-        half = hd // 2
-        inv = 1.0 / (model.theta ** (jnp.arange(half, dtype=jnp.float32)
-                                     / half))
-        ang = positions[:, :, None, None].astype(jnp.float32) * inv
-        c, s = jnp.cos(ang), jnp.sin(ang)
-        t1, t2 = t[..., :half], t[..., half:]
-        return jnp.concatenate([t1 * c - t2 * s, t2 * c + t1 * s], axis=-1)
-
-    h = norm(x, w["ln1"])
-    q = (jnp.dot(h, w["wq"], precision=hi) + w["bq"]).reshape(B, S, nq, hd)
-    k = (jnp.dot(h, w["wk"], precision=hi) + w["bk"]).reshape(B, S, nkv, hd)
-    v = (jnp.dot(h, w["wv"], precision=hi) + w["bv"]).reshape(B, S, nkv, hd)
-    q, k = rope(q), rope(k)
     nb = S // Q_BLOCK
     qb = q.reshape(B, nb, Q_BLOCK, nkv, G, hd).transpose(1, 0, 2, 3, 4, 5)
     pos_k = positions[0]
@@ -76,57 +91,30 @@ def _forward_layer(model: Qwen2, w: dict, x, positions):
         return jnp.einsum("bkgqs,bskd->bqkgd", p, v, precision=hi)
 
     o = lax.map(block, (jnp.arange(nb), qb))
-    o = o.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, nq * hd)
-    x = x + jnp.dot(o, w["wo"], precision=hi)
-    h = norm(x, w["ln2"])
-    a = jax.nn.silu(jnp.dot(h, w["w_gate"], precision=hi))
-    return x + jnp.dot(a * jnp.dot(h, w["w_up"], precision=hi),
-                       w["w_down"], precision=hi)
+    return o.transpose(1, 0, 2, 3, 4, 5).reshape(B, S, nq * hd)
 
 
-def _gaps(model: Qwen2, key, tokens, nxt, control: bool):
-    import jax
+def _gaps(model, key, tokens, nxt, control: bool):
     import jax.numpy as jnp
     from jax import lax
-    hi = lax.Precision.HIGHEST
     B, S = tokens.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
-    g = {n: a.astype(jnp.float32) for n, a in global_weights(model, key).items()}
-    x = jnp.take(g["embed"], tokens, axis=0)
-    xs = (x, x) if control else (x,)
-
-    def body(i, xs):
-        w = {n: a.astype(jnp.float32)
-             for n, a in layer_weights(model, key, i).items()}
-        out = [_forward_layer(model, w, xs[0], positions)]
-        if control:
-            wc = {n: (_fp8_round(a) if n in MATRICES else a)
-                  for n, a in w.items()}
-            out.append(_forward_layer(model, wc, xs[1], positions))
-        return tuple(out)
-
-    xs = lax.fori_loop(0, model.layers, body, xs)
-    head = g["embed"].T if model.tied else g["lm_head"]
-    head_c = _fp8_round(head) if control else None
-
-    def final(h):
-        var = jnp.mean(h * h, axis=-1, keepdims=True)
-        return h * lax.rsqrt(var + model.eps) * (1.0 + g["final_norm"])
-
-    hs = [final(h) for h in xs]
+    hs, head = model.arch.reference_forward(model.sizes, key, tokens,
+                                            positions, control)
+    head_c = fp8_round(head) if control else None
     nr = S // ROW_BLOCK
 
     def rows(args):
         sl = args[0]
         t = args[1]
-        lg = jnp.dot(sl, head, precision=hi)
+        lg = dot(sl, head)
         best = jnp.max(lg, axis=-1)
         mine = jnp.take_along_axis(lg, jnp.maximum(t, 0)[..., None],
                                    axis=-1)[..., 0]
         gap = jnp.where(t >= 0, best - mine, 0.0)
         if not control:
             return (gap,)
-        lc = jnp.dot(args[2], head_c, precision=hi)
+        lc = dot(args[2], head_c)
         pick = jnp.argmax(lc, axis=-1)
         theirs = jnp.take_along_axis(lg, pick[..., None], axis=-1)[..., 0]
         return gap, jnp.where(t >= 0, best - theirs, 0.0)
@@ -140,11 +128,11 @@ def _gaps(model: Qwen2, key, tokens, nxt, control: bool):
 
 
 @functools.lru_cache(maxsize=None)
-def compiled_gaps(model: Qwen2, control: bool):
+def compiled_gaps(model, control: bool):
     """jitted ``(key, tokens (B, S), nxt (B, S)) -> (gaps,)`` or, with
-    ``control``, ``(gaps, control_gaps)``.  ``nxt[b, p]`` is the token
-    served after position ``p``, or -1 where nothing is scored.  S must
-    be a multiple of 256."""
+    ``control``, ``(gaps, control_gaps)`` for a ``program.Model``.
+    ``nxt[b, p]`` is the token served after position ``p``, or -1 where
+    nothing is scored.  S must be a multiple of 256."""
     import jax
     return jax.jit(functools.partial(_gaps, model, control=control))
 

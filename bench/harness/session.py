@@ -17,6 +17,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from harness import program, reference, traffic, warmup
+from harness.spec import ROOT
 from harness.weights import model_key
 
 TRACE_S = 4.0       # seconds of the window a --trace 1 run records
@@ -39,7 +40,8 @@ class Record:
 
 @dataclasses.dataclass
 class Call:
-    """One call into the engine: ``step`` or ``add`` (``add_requests``)."""
+    """One call into the engine: ``step`` or ``add`` (``add_requests``),
+    with the record the call returned (``SpinEngine.step``'s dict)."""
     kind: str
     start: float
     end: float
@@ -47,11 +49,13 @@ class Call:
     width: int = 0
     ctx_cells: int = 0
     tokens: int = 0
+    record: dict = dataclasses.field(default_factory=dict)
 
 
 class Session:
     def __init__(self, cfg: dict, mix: dict, seed: int, peak: dict,
-                 meter, process_start: float, override: Optional[dict] = None):
+                 meter, process_start: float, override: Optional[dict] = None,
+                 root: str = ROOT):
         self.cfg = cfg
         self.mix = mix
         self.seed = int(seed)
@@ -59,8 +63,8 @@ class Session:
         self.meter = meter
         self.process_start = process_start
         self.override = override or {}
-        self.target, self.drafters = program.models(cfg)
-        self.schedule = traffic.Schedule(mix, seed, self.target.vocab)
+        self.target, self.drafters = program.models(cfg, root)
+        self.schedule = traffic.Schedule(mix, seed, self.target.sizes.vocab)
         self.requests: Dict[int, Record] = {}
         self.live: Dict[int, Record] = {}
         self.calls: List[Call] = []
@@ -205,7 +209,8 @@ class Session:
         rows = int(rec.get("active", 0))
         self.calls.append(Call(kind, t0, t1, rows=rows,
                                width=eng.gamma_max, ctx_cells=ctx,
-                               tokens=int(rec.get("tokens", 0))))
+                               tokens=int(rec.get("tokens", 0)),
+                               record=rec))
         with TraceAnnotation("bench.observe"):
             self._observe(t1)
         return rec

@@ -1,14 +1,19 @@
 """Find the pieces of a cell by the names in ``BENCHMARK.json``.
 
-Every configuration, traffic mix and metric reader lives in a file of
-its own under ``bench/``; the harness never lists them in code, so a
-later change adds a cell by adding files and entries only."""
+Every configuration, traffic mix, metric reader and model architecture
+lives in a file of its own under ``bench/``; the harness never lists
+them in code, so a later change adds a cell, or a model of a new
+architecture, by adding files and entries only."""
 
 from __future__ import annotations
 
+import functools
+import hashlib
 import importlib.util
 import json
 import os
+import re
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -82,8 +87,53 @@ def load_reader(name: str, root: str = ROOT):
     """The reader module ``bench/metrics/<name>.py``; its ``read(run)``
     returns the metric's value, or None where it found nothing to read."""
     path = os.path.join(root, "bench", "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+    return _module("bench_metric_" + name.replace(".", "_").replace("-", "_"),
+                   path)
+
+
+def load_arch(model_type: str, root: str = ROOT):
+    """The architecture module ``bench/archs/<model_type>.py``, loaded
+    once per file; a configuration, and each of its drafters, names it
+    by its ``model_type``.  The module gives, with ``m`` the sizes it
+    builds:
+
+    - ``sizes(name, hf)``: hashable sizes from the configuration's (HF)
+      dict, with ``name``, ``vocab`` and ``layers``;
+    - ``model_config(m, dtype)``: the program's ``ModelConfig``;
+    - ``program_params(m, model_key, padded_vocab)``: the program's
+      weight tree, drawn with ``harness.weights`` from the model's key;
+    - ``reference_forward(m, model_key, tokens, positions, control)``:
+      the float32 stack, for ``harness.reference`` (see there);
+    - ``matmul_params``, ``weight_bytes``, ``kv_bytes_per_token``,
+      ``token_flops(m, context)``, ``prefill_flops(m, n)`` and
+      ``verify_work(m, call)``: (FLOPs, bytes) of the verify pass of a
+      traced step's ``session.Call``, whose ``record`` holds what the
+      program's ``step`` returned.
+
+    An unknown type is a KeyError naming the file looked for."""
+    rel = f"bench/archs/{model_type}.py"
+    path = os.path.join(root, *rel.split("/"))
+    if not _ARCH.match(model_type) or not os.path.isfile(path):
+        raise KeyError(f"no architecture {model_type!r}: {rel} not found "
+                       f"under {root}")
+    return _load_once(os.path.realpath(path))
+
+
+_ARCH = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_-]*$")
+
+
+@functools.lru_cache(maxsize=None)
+def _load_once(path: str):
+    # a name of its own file: the module is registered under it, so
+    # that its dataclasses find their module while they are made
+    stem = os.path.basename(path)[:-3].replace("-", "_")
+    digest = hashlib.sha1(path.encode()).hexdigest()[:8]
+    return _module(f"bench_arch_{stem}_{digest}", path)
+
+
+def _module(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
     spec.loader.exec_module(mod)
     return mod

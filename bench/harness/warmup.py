@@ -51,7 +51,7 @@ def serve_sizes(sess):
     rng = np.random.default_rng([sess.seed, 4])
     for i in range(0, len(lengths), cap):
         reqs = [Request(rid=WARM_RID + i + j, dataset="warm", difficulty=0.0,
-                        prompt=rng.integers(0, sess.target.vocab, L)
+                        prompt=rng.integers(0, sess.target.sizes.vocab, L)
                         .astype(np.int32), max_new=1)
                 for j, L in enumerate(lengths[i:i + cap])]
         eng.add_requests(reqs)

@@ -5,7 +5,8 @@
 
 Run it from the root of a checkout that holds ``BENCHMARK.json``,
 ``bench/`` and the program under ``src/``.  The cell names its
-configuration (``bench/configs/``) and traffic mix (``bench/traffic/``);
+configuration (``bench/configs/``), whose models name their
+architecture (``bench/archs/``), and its traffic mix (``bench/traffic/``);
 the metrics it reports are the cell's end-to-end metrics with
 ``--trace 0`` and its per-layer metrics with ``--trace 1``, each read by
 ``bench/metrics/<name>.py``.
@@ -71,7 +72,7 @@ def run_cell(root, name, seed, seconds, trace, require_chip=True,
     program.import_program(root)
     from harness.session import Session
 
-    sess = Session(cfg, mix, seed, peak, meter, PROCESS_START)
+    sess = Session(cfg, mix, seed, peak, meter, PROCESS_START, root=root)
     trace_dir = (os.path.join(root, "bench", ".traces",
                               f"{cell['name']}.{seed}") if trace else None)
     sess.serve(seconds, trace_dir, engine_patch=engine_patch, log=say)

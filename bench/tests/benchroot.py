@@ -1,7 +1,7 @@
 """Build a checkout for the CPU tests: ``BENCHMARK.json`` with the tiny
-cells, the real metric readers and program, and a peaks row for the CPU
-(the tests skip the look for a chip, so nothing they print is a device
-number)."""
+cells, the real metric readers, architectures and program, and a peaks
+row for the CPU (the tests skip the look for a chip, so nothing they
+print is a device number)."""
 
 import json
 import os
@@ -16,8 +16,8 @@ def make_root(tmp) -> str:
     root = os.path.join(str(tmp), "checkout")
     os.makedirs(os.path.join(root, "bench", "configs"))
     os.makedirs(os.path.join(root, "bench", "traffic"))
-    os.symlink(os.path.join(BENCH, "metrics"),
-               os.path.join(root, "bench", "metrics"))
+    for d in ("metrics", "archs"):
+        os.symlink(os.path.join(BENCH, d), os.path.join(root, "bench", d))
     os.symlink(os.path.join(REPO, "src"), os.path.join(root, "src"))
     shutil.copy(os.path.join(HERE, "data", "tiny.json"),
                 os.path.join(root, "bench", "configs", "tiny.json"))

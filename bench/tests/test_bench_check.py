@@ -17,12 +17,13 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 import benchroot  # noqa: E402
-from harness import program, reference, weights  # noqa: E402
+from harness import program, reference, spec, weights  # noqa: E402
 
 program.import_program()
 SEED = 2**33 + 17
-TINY = weights.Qwen2("tiny", hidden=64, inter=128, heads=4, kv_heads=2,
-                     layers=3, vocab=300, eps=1e-6, theta=1e6, tied=False)
+qwen2 = spec.load_arch("qwen2")
+TINY = qwen2.Qwen2("tiny", hidden=64, inter=128, heads=4, kv_heads=2,
+                   layers=3, vocab=300, eps=1e-6, theta=1e6, tied=False)
 
 
 def _run_module():
@@ -59,9 +60,9 @@ def test_weights_are_exact_and_regenerate_layer_by_layer():
     import jax
     import jax.numpy as jnp
     key = weights.model_key(SEED, 0)
-    tree = jax.jit(lambda k: weights.program_params(TINY, k, 512))(key)
+    tree = jax.jit(lambda k: qwen2.program_params(TINY, k, 512))(key)
     for layer in range(TINY.layers):
-        w = weights.layer_weights(TINY, key, layer)
+        w = qwen2.layer_weights(TINY, key, layer)
         got = tree["scan"]["u0_attn"]
         assert jnp.array_equal(got["w_gate"][layer], w["w_gate"])
         assert jnp.array_equal(got["wq"][layer].reshape(64, -1), w["wq"])
@@ -69,11 +70,11 @@ def test_weights_are_exact_and_regenerate_layer_by_layer():
         assert jnp.array_equal(
             w["w_up"].astype(jnp.float32).astype(jnp.bfloat16), w["w_up"])
         assert float(jnp.abs(w["bq"]).max()) > 0
-    g = weights.global_weights(TINY, key)
+    g = qwen2.global_weights(TINY, key)
     assert tree["embed"].shape == (512, 64)
     assert jnp.array_equal(tree["embed"][:300], g["embed"])
     assert not jnp.any(tree["embed"][300:])
-    other = weights.global_weights(TINY, weights.model_key(SEED + 2**32, 0))
+    other = qwen2.global_weights(TINY, weights.model_key(SEED + 2**32, 0))
     assert not jnp.array_equal(other["embed"], g["embed"])
 
 
@@ -85,7 +86,7 @@ def test_reference_agrees_with_the_programs_forward(tied):
     import jax
     import jax.numpy as jnp
     from repro.models import transformer as T
-    m = dataclasses.replace(TINY, tied=tied)
+    m = program.Model(qwen2, dataclasses.replace(TINY, tied=tied))
     b = program.make_bundle(m, SEED, 0, dtype="float32")
     toks = np.random.default_rng(0).integers(0, 300, (2, 512)).astype(np.int32)
     with jax.default_matmul_precision("highest"):
@@ -99,6 +100,91 @@ def test_reference_agrees_with_the_programs_forward(tied):
     nxt[nxt >= 0] = (nxt[nxt >= 0] + 1) % 300
     gaps, = reference.compiled_gaps(m, False)(key, toks, nxt)
     assert float(np.min(np.asarray(gaps)[nxt >= 0])) > 1e-3
+
+
+# Digests of what the harness made for Qwen2 before its weights and
+# reference moved into bench/archs/qwen2.py (sha256 of every leaf's path,
+# shape, dtype and bytes; of the gaps' float32 bytes).  Equal digests
+# mean the same weights on the chip and the same reference readings.
+PINNED_WEIGHTS = {
+    "tiny.target": "1f1208bcc333c5c0bfe60f2797a5fc2a"
+                   "320725965aaf5eb197515a0ac81e0548",
+    "tiny.drafter": "9613808f1ed906f7271e3e17275018e5"
+                    "ec54b78f76fc391243a51a907232f62e",
+    "7b.layer0": "328ad2f6ae6ba24f62a2b9a3f98890f2"
+                 "abde47ad1d72529e979e8cc841b16334",
+}
+PINNED_GAPS = {  # tied: (gaps, control's gaps)
+    False: ("f2b169cedad747997015a554ba627b0a"
+            "80fcb06865e1acf139611f5d84004b9c",
+            "31f9f4227508378d802346b4cbcd18e6"
+            "73c7dc8ee6f2ddd67b15230fa275678f"),
+    True: ("c0409e7a24f0eac76aa4f8c7f94c0a21"
+           "13facda178064c78a83d53af88f09b92",
+           "9915baebb18e3cde242f1f66c65d394f"
+           "4c017e03e4cbbd41cc86435c312f015f"),
+}
+
+
+def _digest(tree):
+    import hashlib
+
+    import jax
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        a = np.asarray(leaf)
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(str(a.shape).encode() + str(a.dtype).encode())
+        h.update(a.tobytes())
+    return h.hexdigest()
+
+
+@pytest.mark.parametrize("which", sorted(PINNED_WEIGHTS))
+def test_qwen2_weights_equal_the_ones_before_the_move(which):
+    """The program's tree for the tiny configuration's models, and the
+    7B configuration's first layer (its vocabulary cut, which no layer
+    leaf depends on), bit for bit as before."""
+    import dataclasses
+    import json
+
+    import jax
+    if which == "7b.layer0":
+        with open(os.path.join(BENCH, "configs",
+                               "qwen2-7b-spin-0.5b.json")) as f:
+            m = qwen2.sizes("t", json.load(f))
+        m = dataclasses.replace(m, layers=1, vocab=512)
+        tree = jax.jit(lambda k: qwen2.program_params(m, k, 512))(
+            weights.model_key(SEED, 0))["scan"]
+    else:
+        with open(os.path.join(TESTS, "data", "tiny.json")) as f:
+            target, drafters = program.models(json.load(f))
+        i = 0 if which == "tiny.target" else 1
+        m = [target, *drafters][i]
+        pv = m.arch.model_config(m.sizes).padded_vocab
+        tree = jax.jit(lambda k: m.arch.program_params(m.sizes, k, pv))(
+            weights.model_key(SEED, i))
+    assert _digest(tree) == PINNED_WEIGHTS[which]
+
+
+@pytest.mark.parametrize("tied", [False, True])
+def test_qwen2_reference_equals_the_one_before_the_move(tied):
+    """The reference's gaps and its float8 control's, on fixed tokens,
+    bit for bit as before."""
+    import dataclasses
+    import hashlib
+    rng = np.random.default_rng(0)
+    toks = rng.integers(0, 300, (2, 512)).astype(np.int32)
+    nxt = rng.integers(0, 300, (2, 512)).astype(np.int32)
+    nxt[:, :10] = -1
+    nxt[1, 400:] = -1
+    m = program.Model(qwen2, dataclasses.replace(TINY, tied=tied))
+    key = weights.model_key(SEED, 0)
+    got = [np.asarray(g) for g in (
+        reference.compiled_gaps(m, False)(key, toks, nxt)
+        + reference.compiled_gaps(m, True)(key, toks, nxt))]
+    digests = [hashlib.sha256(g.tobytes()).hexdigest() for g in got]
+    want = PINNED_GAPS[tied]
+    assert digests == [want[0], want[0], want[1]]
 
 
 @pytest.fixture(scope="module")

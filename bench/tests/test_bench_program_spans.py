@@ -193,3 +193,30 @@ def test_a_cpu_trace_has_spans_but_no_device(traced, monkeypatch):
     assert program_spans.phases(sess) is None
     for name in PHASE_METRICS:
         assert spec.load_reader(name, root).read(sess) is None
+
+
+def test_verify_work_gets_each_traced_steps_call(traced, monkeypatch):
+    """``verify_roofline`` hands the target's architecture the ``Call``
+    of every traced step, with the record ``SpinEngine.step`` returned."""
+    import types
+    root, sess = traced
+    steps = sess.traced_steps()
+    assert steps
+    for c in steps:
+        assert c.record["active"] == c.rows
+        assert c.record["tokens"] == c.tokens
+        assert "micro_batches" in c.record
+    seen = []
+
+    def verify_work(m, call):
+        assert m is sess.target.sizes
+        seen.append(call)
+        return 1.0, 1.0
+
+    arch = types.SimpleNamespace(verify_work=verify_work)
+    monkeypatch.setattr(sess, "target", program.Model(arch, sess.target.sizes))
+    monkeypatch.setattr(sess, "trace",
+                        types.SimpleNamespace(kind_s={"verify": 1.0}))
+    assert spec.load_reader("verify_roofline", root).read(sess) is not None
+    assert len(seen) == len(steps)
+    assert all(a is b for a, b in zip(seen, steps))
